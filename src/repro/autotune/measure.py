@@ -172,9 +172,10 @@ class AnalyticDslashModel:
     """(perf, power) of the T-blocked D-slash kernel for ``{t_block}``.
 
     Memory-bound (the paper's thesis): time is streaming traffic over
-    HBM bandwidth plus per-grid-step overhead; VMEM must hold the spinor
-    + gauge block for ``t_block`` time slices (plus the two halo
-    slices)."""
+    HBM bandwidth plus per-grid-step overhead.  Bytes are the kernels'
+    site-minor layout as the TPU tiles it: (X, Y*Z) planes padded to
+    (8, 128).  A grid step's blocks must fit the kernels' scoped-VMEM
+    cap (``kernels.dslash.kernel.t_block_fits``)."""
 
     lat: Tuple[int, int, int, int]
     real_bytes: int = 4            # float32 split re/im on TPU
@@ -183,22 +184,21 @@ class AnalyticDslashModel:
         return self.evaluate(point)
 
     def evaluate(self, point: Point) -> Tuple[float, float]:
+        from repro.kernels.dslash.kernel import _plane_bytes, t_block_fits
         from repro.lqcd.dirac import (dslash_bytes_per_site,
                                       dslash_flops_per_site)
         tb = int(point["t_block"])
         X, Y, Z, T = self.lat
-        if T % tb:
+        if not t_block_fits(self.lat, tb):
             return INFEASIBLE
         vol = X * Y * Z * T
-        site_bytes = (4 * 18 + 24) * self.real_bytes   # links + spinor
-        vmem = X * Y * Z * (tb + 2) * site_bytes * 2   # in + out blocks
-        if vmem > VMEM_BUDGET:
-            return INFEASIBLE
+        plane = _plane_bytes(X, Y * Z)            # one real per site, tiled
+        pad = plane / (X * Y * Z * 4)
         flops = vol * dslash_flops_per_site()
         hbm = vol * dslash_bytes_per_site(self.real_bytes,
-                                          compressed_links=False)
-        # T-halo slices are re-fetched once per grid step
-        hbm += (T // tb) * 2 * X * Y * Z * site_bytes
+                                          compressed_links=False) * pad
+        # per grid step the halos re-fetch two half spinors and a t-link
+        hbm += (T // tb) * (12 + 12 + 18) * plane
         memory_s = hbm / hw.HBM_BW
         compute_s = flops / hw.PEAK_BF16_FLOPS
         t = max(memory_s, compute_s) + (T // tb) * GRID_STEP_OVERHEAD_S

@@ -123,10 +123,14 @@ def dgemm_tile_space(m: int, k: int, n: int,
 
 def dslash_tile_space(lat: Tuple[int, int, int, int],
                       choices: Sequence[int] = (1, 2, 4, 8)) -> Space:
-    """T-axis block candidates for the D-slash kernels (grid runs over
-    T / t_block; t_block must divide T).  Blocks are capped at T/2 so
-    the ±1 halo slices always come from *neighboring* grid blocks — the
-    kernel's overlapping index maps are validated in that regime."""
+    """T-axis block candidates for the D-slash kernels that compile: the
+    grid runs over T / t_block, so t_block must divide T, and a grid
+    step's blocks must fit the kernels' scoped-VMEM cap (counted in tiled
+    bytes for the even-odd kernel, the larger of the two).  Blocks are
+    capped at T/2 so the ±1 halo slices always come from *neighbouring*
+    grid blocks.  ``t_block=1`` (halos only) is always offered."""
+    from repro.kernels.dslash.kernel import t_block_fits
     T = lat[3]
-    capped = [c for c in choices if c <= max(T // 2, 1)]
-    return Space({"t_block": _tile_candidates(T, capped or [1])})
+    ok = [c for c in choices
+          if c == 1 or (c <= T // 2 and t_block_fits(lat, c))]
+    return Space({"t_block": _tile_candidates(T, ok)})

@@ -229,7 +229,10 @@ def lattice_mesh(t_extent: int, n_devices: Optional[int] = None,
     """
     avail = n_devices or jax.device_count()
     n = max(d for d in range(1, avail + 1) if t_extent % d == 0)
-    return jax.make_mesh((n,), (axis_name,))
+    # Auto axes, like repro.launch.mesh: plain jnp code on a solver's
+    # T-sharded output then runs instead of raising ShardingTypeError
+    return jax.make_mesh((n,), (axis_name,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def lattice_eo_specs(axis_name: str = TP) -> Tuple[P, P]:

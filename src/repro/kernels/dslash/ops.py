@@ -3,7 +3,7 @@ kernels.
 
 ``tuned=True`` resolves ``t_block`` from the autotune cache for this
 lattice and backend (``repro.autotune``; analytic roofline tuner on a
-cache miss) instead of the static default of 4.
+cache miss) instead of the static default of 1.
 """
 from __future__ import annotations
 
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.kernels.dslash.kernel import dslash_eo_split, dslash_split
 from repro.kernels.dslash.ref import from_split, to_split
 
-DEFAULT_T_BLOCK = 4
+DEFAULT_T_BLOCK = 1
 
 
 def _resolve_t_block(t_block: int | None, tuned: bool,
@@ -38,23 +38,39 @@ def sharded_t_block(local_lat: tuple) -> int:
     return int(tuned_config("dslash", lat)["t_block"])
 
 
+def _interpret_default(interpret: bool | None) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
 @partial(jax.jit, static_argnames=("t_block", "interpret"))
 def _dslash_call(U: jnp.ndarray, psi: jnp.ndarray, *, t_block: int,
                  interpret: bool) -> jnp.ndarray:
-    out_s = dslash_split(to_split(U), to_split(psi), t_block=t_block,
+    Z = psi.shape[2]
+    out_s = dslash_split(to_split(U), to_split(psi), Z, t_block=t_block,
                          interpret=interpret)
-    return from_split(out_s)
+    return from_split(out_s, Z)
 
 
 def dslash_pallas(U: jnp.ndarray, psi: jnp.ndarray, *,
                   t_block: int | None = None, tuned: bool = False,
                   interpret: bool | None = None) -> jnp.ndarray:
     """Complex-in/complex-out D-slash via the split-field Pallas kernel."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # gauge layout is (4, X, Y, Z, T, 3, 3): direction axis leads
     t_block = _resolve_t_block(t_block, tuned, tuple(U.shape[1:5]))
-    return _dslash_call(U, psi, t_block=t_block, interpret=interpret)
+    return _dslash_call(U, psi, t_block=t_block,
+                        interpret=_interpret_default(interpret))
+
+
+def dslash_half_split(U_out: jnp.ndarray, U_src: jnp.ndarray,
+                      psi: jnp.ndarray, src_parity: int, *, t_block: int,
+                      interpret: bool) -> jnp.ndarray:
+    """Complex compact half-fields through the even-odd kernel (traceable;
+    the sharded path calls it per shard on halo-padded blocks)."""
+    Z = psi.shape[2]
+    out_s = dslash_eo_split(to_split(U_out), to_split(U_src), to_split(psi),
+                            src_parity, Z, t_block=t_block,
+                            interpret=interpret)
+    return from_split(out_s, Z)
 
 
 @partial(jax.jit, static_argnames=("src_parity", "t_block", "interpret"))
@@ -62,9 +78,8 @@ def _dslash_half_call(U_e: jnp.ndarray, U_o: jnp.ndarray, psi: jnp.ndarray,
                       src_parity: int, *, t_block: int,
                       interpret: bool) -> jnp.ndarray:
     U_out, U_src = (U_o, U_e) if src_parity == 0 else (U_e, U_o)
-    out_s = dslash_eo_split(to_split(U_out), to_split(U_src), to_split(psi),
-                            src_parity, t_block=t_block, interpret=interpret)
-    return from_split(out_s)
+    return dslash_half_split(U_out, U_src, psi, src_parity, t_block=t_block,
+                             interpret=interpret)
 
 
 def dslash_half_pallas(U_e: jnp.ndarray, U_o: jnp.ndarray, psi: jnp.ndarray,
@@ -78,9 +93,7 @@ def dslash_half_pallas(U_e: jnp.ndarray, U_o: jnp.ndarray, psi: jnp.ndarray,
     parity.  ``U_e``/``U_o`` are the packed gauge halves from
     ``repro.lqcd.eo.pack_gauge``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # the packed half-lattice keeps the full T extent (X is halved)
     t_block = _resolve_t_block(t_block, tuned, tuple(U_e.shape[1:5]))
     return _dslash_half_call(U_e, U_o, psi, src_parity, t_block=t_block,
-                             interpret=interpret)
+                             interpret=_interpret_default(interpret))

@@ -80,6 +80,8 @@ def _make_demo_trace(args) -> None:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3-8b")
     ap.add_argument("--smoke", action="store_true", default=True)

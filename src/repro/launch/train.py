@@ -28,6 +28,8 @@ from repro.runtime.steps import make_train_step
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="olmo-1b")
     ap.add_argument("--smoke", action="store_true", default=True)

@@ -8,6 +8,7 @@ CGNE on the normal equations M†M x = M† b (M is not hermitian), with the
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Callable, NamedTuple
 
 import jax
@@ -106,6 +107,65 @@ def _round_complex(v: jnp.ndarray, dtype) -> jnp.ndarray:
     return (re + 1j * im).astype(jnp.complex64)
 
 
+@jax.jit
+def _eo_setup(U: jnp.ndarray, b: jnp.ndarray):
+    """Gauge halves, source halves and ‖b‖ for the even-odd solve."""
+    from repro.lqcd.eo import eo_pack, pack_gauge
+    U_e, U_o = pack_gauge(U)
+    return U_e, U_o, eo_pack(b, 0), eo_pack(b, 1), jnp.sqrt(_dot(b, b))
+
+
+@partial(jax.jit, static_argnames=("inner_dtype",))
+def _eo_system(U_e, U_o, b_e, b_o, kappa, *, inner_dtype):
+    """Schur right-hand side b_e + κ D_eo b_o, and the gauge halves the
+    inner CG streams (rounded through ``inner_dtype``)."""
+    from repro.lqcd.eo import eo_rhs
+    return (eo_rhs(U_e, U_o, b_e, b_o, kappa),
+            _round_complex(U_e, inner_dtype), _round_complex(U_o, inner_dtype))
+
+
+@jax.jit
+def _eo_defect_rhs(U_e, U_o, r_s, kappa):
+    """A† r_s: right-hand side of the defect normal equations."""
+    from repro.lqcd.eo import schur_matvec_dagger
+    return schur_matvec_dagger(U_e, U_o, r_s, kappa)
+
+
+@partial(jax.jit, static_argnames=("inner_dtype",))
+def _eo_inner(U_e, U_o, rhs_n, kappa, eta, cap, *, inner_dtype):
+    """Inner CG on A†A e = rhs_n, fields rounded through ``inner_dtype``
+    (``U_e``/``U_o`` already rounded), at most ``cap`` normal ops."""
+    from repro.lqcd.eo import schur_matvec, schur_matvec_dagger
+
+    def normal(v):
+        v = _round_complex(v, inner_dtype)
+        av = _round_complex(schur_matvec(U_e, U_o, v, kappa), inner_dtype)
+        return _round_complex(schur_matvec_dagger(U_e, U_o, av, kappa),
+                              inner_dtype)
+
+    inner = cg_solve(normal, rhs_n, tol=eta, max_iters=cap)
+    return inner.x, inner.iters
+
+
+@jax.jit
+def _eo_update(U_e, U_o, rhs_e, x_e, e, kappa):
+    """x_e += e and the f32 Schur residual r_s = rhs_e − A x_e."""
+    from repro.lqcd.eo import schur_matvec
+    x_e = x_e + e
+    r_s = rhs_e - schur_matvec(U_e, U_o, x_e, kappa)
+    return x_e, r_s, jnp.sqrt(_dot(r_s, r_s))
+
+
+@jax.jit
+def _eo_finish(U, U_e, U_o, x_e, b, b_o, kappa):
+    """Back-substitute the odd sites and take the true ‖b − M x‖ with the
+    full-lattice operator, which shares no code with the even-odd one."""
+    from repro.lqcd.eo import eo_unpack, reconstruct_odd
+    x = eo_unpack(x_e, reconstruct_odd(U_e, U_o, x_e, b_o, kappa))
+    true_r = b - wilson_matvec(U, x, kappa)
+    return x, jnp.sqrt(_dot(true_r, true_r))
+
+
 def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
                     tol: float = 1e-6, max_iters: int = 1000,
                     inner_dtype=None, inner_tol: float = 1e-2,
@@ -121,7 +181,9 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
     the even-system residual.  With ``inner_dtype`` set (e.g.
     ``jnp.bfloat16``), the inner CG streams fields rounded through that
     dtype and the outer loop re-computes the residual in f32 and restarts —
-    the reliable-update scheme the paper's single/double CG uses.
+    the reliable-update scheme the paper's single/double CG uses.  The
+    round cap, κ and the inner tolerance are traced, so the outer rounds
+    reuse one set of compiled programs per lattice shape.
 
     With ``mesh`` set, the Schur operators and the whole inner CG run
     T-sharded over the mesh's ``axis_name`` axis
@@ -129,20 +191,18 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
     interior compute (``overlap``), the inner ``while_loop`` stays inside
     one ``shard_map`` with ``psum`` reductions only, and
     ``backend="pallas"`` routes local hops through the autotuned Pallas
-    kernel on halo-padded blocks.
+    kernel on halo-padded blocks.  Back-substitution and the true residual
+    stay T-sharded too (no field is gathered onto one device).
     """
-    from repro.lqcd.eo import (eo_pack, eo_rhs, eo_unpack, pack_gauge,
-                               reconstruct_odd, schur_matvec,
-                               schur_matvec_dagger)
-
-    U_e, U_o = pack_gauge(U)
-    b_e, b_o = eo_pack(b, 0), eo_pack(b, 1)
-    b_norm = float(jnp.sqrt(_dot(b, b)))
+    U_e, U_o, b_e, b_o, b_norm = _eo_setup(U, b)
+    b_norm = float(b_norm)
     # no low-precision pass gets below its own roundoff; full precision
     # drives straight to tol in one outer sweep
     eta = inner_tol if inner_dtype is not None else tol
 
     if mesh is not None:
+        from repro.lqcd.eo import eo_unpack
+        from repro.lqcd.multichip import dslash_sharded
         from repro.lqcd.multichip_eo import ShardedWilsonEO
         hi = ShardedWilsonEO(U_e, U_o, kappa, mesh, axis_name=axis_name,
                              overlap=overlap, backend=backend)
@@ -153,47 +213,40 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
             kappa, mesh, axis_name=axis_name, overlap=overlap,
             backend=backend)
         rhs_e = hi.rhs(b_e, b_o)
-        schur = hi.schur
-        schur_dagger = hi.schur_dagger
 
-        def run_inner(rhs_n, cap):
-            return lo.cg_normal(rhs_n, tol=eta, max_iters=cap,
-                                inner_dtype=inner_dtype)
+        def run_round(x_e, r_s, cap):
+            inner = lo.cg_normal(hi.schur_dagger(r_s), tol=eta,
+                                 max_iters=cap, inner_dtype=inner_dtype)
+            x_e = x_e + inner.x
+            r_s = rhs_e - hi.schur(x_e)
+            return x_e, r_s, jnp.sqrt(_dot(r_s, r_s)), inner.iters
+
+        def finish(x_e):
+            x = eo_unpack(x_e, hi.reconstruct(x_e, b_o))
+            true_r = b - (x - kappa * dslash_sharded(U, x, mesh, axis_name))
+            return x, jnp.sqrt(_dot(true_r, true_r))
     else:
-        rhs_e = eo_rhs(U_e, U_o, b_e, b_o, kappa)
+        rhs_e, *U_lo = _eo_system(U_e, U_o, b_e, b_o, kappa,
+                                  inner_dtype=inner_dtype)
 
-        def schur(v):
-            return schur_matvec(U_e, U_o, v, kappa)
+        def run_round(x_e, r_s, cap):
+            # three programs, not one: XLA would keep the Schur operators'
+            # temporaries beside the inner loop's
+            rhs_n = _eo_defect_rhs(U_e, U_o, r_s, kappa)
+            e, iters = _eo_inner(*U_lo, rhs_n, kappa, eta, cap,
+                                 inner_dtype=inner_dtype)
+            return _eo_update(U_e, U_o, rhs_e, x_e, e, kappa) + (iters,)
 
-        def schur_dagger(v):
-            return schur_matvec_dagger(U_e, U_o, v, kappa)
-
-        def normal_hi(v):
-            return schur_dagger(schur(v))
-
-        if inner_dtype is not None:
-            U_e_lo = _round_complex(U_e, inner_dtype)
-            U_o_lo = _round_complex(U_o, inner_dtype)
-
-            def normal_lo(v):
-                v = _round_complex(v, inner_dtype)
-                av = schur_matvec(U_e_lo, U_o_lo, v, kappa)
-                av = _round_complex(av, inner_dtype)
-                out = schur_matvec_dagger(U_e_lo, U_o_lo, av, kappa)
-                return _round_complex(out, inner_dtype)
-        else:
-            normal_lo = normal_hi
-
-        def run_inner(rhs_n, cap):
-            return cg_solve(normal_lo, rhs_n, tol=eta, max_iters=cap)
+        def finish(x_e):
+            return _eo_finish(U, U_e, U_o, x_e, b, b_o, kappa)
 
     x_e = jnp.zeros_like(rhs_e)
     r_s = rhs_e                              # Schur-system residual
+    r_norm = float(jnp.sqrt(_dot(r_s, r_s)))
     total_inner = 0
     outer = 0
     while outer < max_outer and total_inner < max_iters:
-        rel = float(jnp.sqrt(_dot(r_s, r_s))) / max(b_norm, 1e-30)
-        if rel <= tol:
+        if r_norm / max(b_norm, 1e-30) <= tol:
             break
         # inner CG on the defect equation A†A e = A† r_s, reduced precision.
         # Cap each low-precision restart so a stalled inner solve (roundoff
@@ -201,17 +254,13 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
         remaining = max_iters - total_inner
         round_cap = (remaining if inner_dtype is None
                      else min(remaining, max(10, max_iters // 5)))
-        rhs_n = schur_dagger(r_s)
-        inner = run_inner(rhs_n, round_cap)
-        total_inner += int(inner.iters)
-        x_e = x_e + inner.x
-        r_s = rhs_e - schur(x_e)             # recompute in full precision
+        x_e, r_s, r_norm, iters = run_round(x_e, r_s, jnp.int32(round_cap))
+        total_inner += int(iters)
+        r_norm = float(r_norm)
         outer += 1
 
-    x_o = reconstruct_odd(U_e, U_o, x_e, b_o, kappa)
-    x = eo_unpack(x_e, x_o)
-    true_r = b - wilson_matvec(U, x, kappa)
-    rel = float(jnp.sqrt(_dot(true_r, true_r))) / max(b_norm, 1e-30)
+    x, true_norm = finish(x_e)
+    rel = float(true_norm) / max(b_norm, 1e-30)
     return EOCGResult(x, total_inner, outer, rel, rel <= tol)
 
 

@@ -38,22 +38,10 @@ from typing import Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.lqcd.dirac import EYE4, GAMMA, GAMMA5
+from repro.lqcd.dirac import EYE4, GAMMA, GAMMA5, mv, mv_dag, spin
 
 PROJ_M = jnp.stack([EYE4 - GAMMA[mu] for mu in range(4)])   # (1 - gamma_mu)
 PROJ_P = jnp.stack([EYE4 + GAMMA[mu] for mu in range(4)])   # (1 + gamma_mu)
-
-
-def mv(u, v):                         # U_ab psi_sb -> psi_sa
-    return jnp.einsum("...ab,...sb->...sa", u, v)
-
-
-def mv_dag(u, v):                     # (U^dagger)_ab psi_sb
-    return jnp.einsum("...ba,...sb->...sa", jnp.conj(u), v)
-
-
-def spin(proj, v):
-    return jnp.einsum("st,...ta->...sa", proj, v)
 
 
 def _sublattice_offset(shape: Tuple[int, ...], parity: int) -> np.ndarray:
@@ -64,29 +52,37 @@ def _sublattice_offset(shape: Tuple[int, ...], parity: int) -> np.ndarray:
     return ((y + z + t + parity) % 2)[None]
 
 
+def _offset_mask(shape: Tuple[int, ...], parity: int, ndim: int):
+    """s(y,z,t) == 1 for ``parity``, shaped to broadcast against a compact
+    field of ``ndim`` dims (site axes lead)."""
+    s = _sublattice_offset(shape, parity)
+    return (s == 1).reshape(s.shape + (1,) * (ndim - 4))
+
+
 def eo_pack(field: jnp.ndarray, parity: int) -> jnp.ndarray:
     """Gather the ``parity`` sites of a full-lattice field (site axes lead)
-    into the compact (X//2, Y, Z, T, ...) layout."""
+    into the compact (X//2, Y, Z, T, ...) layout.
+
+    Pure selection (reshape x into pairs, pick the pair member s), so a
+    field sharded on T stays sharded."""
     X = field.shape[0]
     if X % 2:
         raise ValueError(
             f"even-odd packing needs an even x extent, got X={X}")
-    s = _sublattice_offset(field.shape, parity)
-    x_idx = 2 * np.arange(X // 2)[:, None, None, None] + s[0]
-    y, z, t = np.indices(field.shape[1:4])
-    return field[x_idx, y[None], z[None], t[None]]
+    pairs = field.reshape((X // 2, 2) + field.shape[1:])
+    odd = _offset_mask(field.shape, parity, field.ndim)
+    return jnp.where(odd, pairs[:, 1], pairs[:, 0])
 
 
 def eo_unpack(half_e: jnp.ndarray, half_o: jnp.ndarray) -> jnp.ndarray:
     """Interleave compact even/odd half-fields back into a full field."""
-    Xh, Y, Z, T = half_e.shape[:4]
-    full = jnp.zeros((2 * Xh,) + half_e.shape[1:], half_e.dtype)
-    y, z, t = np.indices((Y, Z, T))
-    for parity, half in ((0, half_e), (1, half_o)):
-        s = _sublattice_offset((2 * Xh, Y, Z, T), parity)
-        x_idx = 2 * np.arange(Xh)[:, None, None, None] + s[0]
-        full = full.at[x_idx, y[None], z[None], t[None]].set(half)
-    return full
+    Xh = half_e.shape[0]
+    full_shape = (2 * Xh,) + half_e.shape[1:]
+    # where the even site comes second in its x pair, the odd one is first
+    even_second = _offset_mask(full_shape, 0, half_e.ndim)
+    first = jnp.where(even_second, half_o, half_e)
+    second = jnp.where(even_second, half_e, half_o)
+    return jnp.stack([first, second], axis=1).reshape(full_shape)
 
 
 def pack_gauge(U: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -176,8 +172,7 @@ def schur_matvec_dagger(U_e: jnp.ndarray, U_o: jnp.ndarray,
     """A^dagger via gamma5-hermiticity: A^dagger = gamma5 A gamma5 (the
     parity projection commutes with gamma5, so the identity survives the
     Schur reduction)."""
-    g5 = lambda v: jnp.einsum("st,...ta->...sa", GAMMA5, v)  # noqa: E731
-    return g5(schur_matvec(U_e, U_o, g5(psi_e), kappa))
+    return spin(GAMMA5, schur_matvec(U_e, U_o, spin(GAMMA5, psi_e), kappa))
 
 
 def eo_rhs(U_e: jnp.ndarray, U_o: jnp.ndarray, b_e: jnp.ndarray,

@@ -8,8 +8,8 @@ projector ``(1 ∓ γ_t)`` in the Dirac basis is ``diag(0,0,2,2)`` /
 ``diag(2,2,0,0)``, so only two of the four spin components of a halo
 slice ever enter the t-direction hop.  With ``compress=True`` (default)
 only those two components cross the wire — half the spinor halo bytes —
-and the result is **bit-identical** in f32, because the dropped einsum
-terms were exact zero-adds.
+and the result is **bit-identical** in f32, because the dropped terms
+were exact zero-adds.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.lqcd.dirac import EYE4, GAMMA
+from repro.lqcd.dirac import EYE4, GAMMA, mv, mv_dag, spin
 
 T_AX = 3
 
@@ -40,8 +40,7 @@ def halo_perms(n: int):
 
 def _halo_exchange(x: jnp.ndarray, axis_name: str, t_axis: int):
     """Returns (from_next_first_slice, from_prev_last_slice)."""
-    from repro.compat import axis_size
-    fwd_perm, bwd_perm = halo_perms(axis_size(axis_name))
+    fwd_perm, bwd_perm = halo_perms(jax.lax.axis_size(axis_name))
     first = jax.lax.slice_in_dim(x, 0, 1, axis=t_axis)
     last = jax.lax.slice_in_dim(x, x.shape[t_axis] - 1, x.shape[t_axis],
                                 axis=t_axis)
@@ -57,6 +56,20 @@ def scatter_spin(v: jnp.ndarray, lo: int) -> jnp.ndarray:
     return jax.lax.dynamic_update_slice_in_dim(z, v, lo, axis=-2)
 
 
+def _t_slice(x: jnp.ndarray, start: int, stop: int):
+    """T rows [start, stop) of a local block, or ``None`` when empty
+    (T_local == 1 leaves no interior rows)."""
+    return (jax.lax.slice_in_dim(x, start, stop, axis=T_AX)
+            if stop > start else None)
+
+
+def _t_join(a, b) -> jnp.ndarray:
+    """Concatenate along T, skipping an empty (``None``) side."""
+    if a is None or b is None:
+        return b if a is None else a
+    return jnp.concatenate([a, b], axis=T_AX)
+
+
 def _dslash_local(U_loc: jnp.ndarray, psi_loc: jnp.ndarray,
                   axis_name: str, compress: bool) -> jnp.ndarray:
     """D-slash body on a T-sharded block: x/y/z via local rolls; T via halos."""
@@ -67,12 +80,10 @@ def _dslash_local(U_loc: jnp.ndarray, psi_loc: jnp.ndarray,
         g = GAMMA[mu]
         u = U_loc[mu]
         psi_f = jnp.roll(psi_loc, -1, axis=mu)
-        hop_f = jnp.einsum("...ab,...sb->...sa", u, psi_f)
-        out = out + jnp.einsum("st,...ta->...sa", EYE4 - g, hop_f)
+        out = out + spin(EYE4 - g, mv(u, psi_f))
         u_b = jnp.roll(u, 1, axis=mu)
         psi_b = jnp.roll(psi_loc, 1, axis=mu)
-        hop_b = jnp.einsum("...ba,...sb->...sa", jnp.conj(u_b), psi_b)
-        out = out + jnp.einsum("st,...ta->...sa", EYE4 + g, hop_b)
+        out = out + spin(EYE4 + g, mv_dag(u_b, psi_b))
     # time direction: halo exchange over the mesh axis
     g = GAMMA[3]
     u_t = U_loc[3]
@@ -88,8 +99,7 @@ def _dslash_local(U_loc: jnp.ndarray, psi_loc: jnp.ndarray,
         # result is bit-compatible with the full-slice exchange.  Bonus:
         # only one gauge ppermute (the -t hop's last link slice) instead of
         # the uncompressed path's two.
-        from repro.compat import axis_size
-        fwd_perm, bwd_perm = halo_perms(axis_size(axis_name))
+        fwd_perm, bwd_perm = halo_perms(jax.lax.axis_size(axis_name))
         send_f = jax.lax.slice_in_dim(psi_loc, 0, 1, axis=T_AX)[..., 2:4, :]
         send_b = jax.lax.slice_in_dim(psi_loc, Tl - 1, Tl,
                                       axis=T_AX)[..., 0:2, :]
@@ -102,19 +112,11 @@ def _dslash_local(U_loc: jnp.ndarray, psi_loc: jnp.ndarray,
     else:
         psi_next, psi_prev = _halo_exchange(psi_loc, axis_name, T_AX)
         u_prev_last = _halo_exchange(u_t, axis_name, T_AX)[1]
-    psi_f = jnp.concatenate(
-        [jax.lax.slice_in_dim(psi_loc, 1, Tl, axis=T_AX), psi_next],
-        axis=T_AX)
-    hop_f = jnp.einsum("...ab,...sb->...sa", u_t, psi_f)
-    out = out + jnp.einsum("st,...ta->...sa", EYE4 - g, hop_f)
-    psi_b = jnp.concatenate(
-        [psi_prev,
-         jax.lax.slice_in_dim(psi_loc, 0, Tl - 1, axis=T_AX)], axis=T_AX)
-    u_b = jnp.concatenate(
-        [u_prev_last,
-         jax.lax.slice_in_dim(u_t, 0, Tl - 1, axis=T_AX)], axis=T_AX)
-    hop_b = jnp.einsum("...ba,...sb->...sa", jnp.conj(u_b), psi_b)
-    out = out + jnp.einsum("st,...ta->...sa", EYE4 + g, hop_b)
+    psi_f = _t_join(_t_slice(psi_loc, 1, Tl), psi_next)
+    out = out + spin(EYE4 - g, mv(u_t, psi_f))
+    psi_b = _t_join(psi_prev, _t_slice(psi_loc, 0, Tl - 1))
+    u_b = _t_join(u_prev_last, _t_slice(u_t, 0, Tl - 1))
+    out = out + spin(EYE4 + g, mv_dag(u_b, psi_b))
     return out
 
 
@@ -129,8 +131,7 @@ def dslash_sharded(U: jnp.ndarray, psi: jnp.ndarray, mesh,
     """
     u_spec = P(None, None, None, None, axis_name, None, None)
     psi_spec = P(None, None, None, axis_name, None, None)
-    from repro.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         partial(_dslash_local, axis_name=axis_name, compress=compress),
         mesh=mesh, in_specs=(u_spec, psi_spec), out_specs=psi_spec,
         check_vma=False)(U, psi)

@@ -183,8 +183,7 @@ def _half_hop_pallas_local(U_out_pad: jnp.ndarray, U_src_pad: jnp.ndarray,
     ``src_parity_eff`` absorbs the pad's t-shift of 1 (requires even
     ``T_local`` so every shard sees the same static parity).
     """
-    from repro.kernels.dslash.kernel import dslash_eo_split
-    from repro.kernels.dslash.ref import from_split, to_split
+    from repro.kernels.dslash.ops import dslash_half_split
 
     Tl = psi.shape[T_AX]
     fwd_perm, bwd_perm = halo_perms(n_shards)
@@ -195,9 +194,9 @@ def _half_hop_pallas_local(U_out_pad: jnp.ndarray, U_src_pad: jnp.ndarray,
     psi_pad = jnp.concatenate(
         [scatter_spin(from_prev, 0), psi, scatter_spin(from_next, 2)],
         axis=T_AX)
-    out_pad = from_split(dslash_eo_split(
-        to_split(U_out_pad), to_split(U_src_pad), to_split(psi_pad),
-        src_parity_eff, t_block=t_block, interpret=interpret))
+    out_pad = dslash_half_split(U_out_pad, U_src_pad, psi_pad,
+                                src_parity_eff, t_block=t_block,
+                                interpret=interpret)
     return jax.lax.slice_in_dim(out_pad, 1, Tl + 1, axis=T_AX)
 
 
@@ -290,9 +289,8 @@ class ShardedWilsonEO:
         return v - (self.kappa * self.kappa) * d
 
     def _shmap(self, f, in_specs, out_specs):
-        from repro.compat import shard_map
-        return shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _jitted(self, key, build):
         fn = self._jit_cache.get(key)

@@ -263,9 +263,8 @@ def _seq_sharded_attention(q, k, v, *, mesh, data_axes, causal, window,
                                    q_offset=off, window=window)
 
     spec = P(data_axes, model_axis, None, None)
-    from repro.compat import shard_map
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def gqa_forward(cfg: ModelConfig, p: Params, x: jnp.ndarray, *,

@@ -184,8 +184,7 @@ def _ep_data_forward(cfg: ModelConfig, p: Params, x, mesh, data_axes,
                 P(data_axes, None, model_axis),    # (E, D, F)
                 P(data_axes, None, model_axis),
                 P(data_axes, model_axis, None))    # (E, F, D)
-    from repro.compat import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(P(data_axes, None, None), P()),
         check_vma=False,
@@ -258,8 +257,7 @@ def moe_forward(cfg: ModelConfig, p: Params, x: jnp.ndarray, *,
                 aux = jax.lax.pmean(aux, a)
             return y.reshape(xl.shape), aux
 
-        from repro.compat import shard_map
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(data_axes, None, None), P()),

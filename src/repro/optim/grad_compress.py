@@ -33,8 +33,7 @@ def compressed_psum_leaf(g: jnp.ndarray, err: jnp.ndarray, axis: str,
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One leaf: quantize(g + err) -> psum(int32) -> dequantize; returns
     (reduced gradient, new error feedback)."""
-    from repro.compat import axis_size
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     g_fb = g.astype(jnp.float32) + err
     q, scale = quantize_int8(g_fb)
     # int8 sums can overflow int8; widen to int32 on the wire model —
@@ -63,8 +62,7 @@ def compressed_pod_mean(grads: Any, err_state: Any, mesh,
 
         spec = P()   # leaves arrive pod-replicated per-shard; shard_map over
         # pod only: treat other axes as replicated within this collective
-        from repro.compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P()), out_specs=(P(), P()),
             axis_names={pod_axis}, check_vma=False)(g, e)

@@ -30,7 +30,6 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from repro.compat import trapezoid
 
 NETWORK = "network"
 
@@ -101,7 +100,7 @@ class PowerTrace:
         """∫flops_rate dt — over [t0, t1] when given, else the whole
         trace (the flops counterpart of :meth:`energy_j`)."""
         if t0 is None and t1 is None:
-            return float(trapezoid(self.flops_rate, self.t))
+            return float(np.trapezoid(self.flops_rate, self.t))
         t0 = float(self.t[0]) if t0 is None else t0
         t1 = float(self.t[-1]) if t1 is None else t1
         return self._window_integral(self.flops_rate, t0, t1)
@@ -113,7 +112,7 @@ class PowerTrace:
         ts = np.concatenate(([t0], self.t[m], [t1]))
         ys = np.concatenate(([np.interp(t0, self.t, y)], y[m],
                              [np.interp(t1, self.t, y)]))
-        return float(trapezoid(ys, ts))
+        return float(np.trapezoid(ys, ts))
 
     def avg_power(self, t0: Optional[float] = None,
                   t1: Optional[float] = None,
@@ -140,13 +139,13 @@ class PowerTrace:
         if include_network and net is not None:
             total = total + net
         if t0 is None and t1 is None:
-            return float(trapezoid(total, self.t))
+            return float(np.trapezoid(total, self.t))
         t0 = float(self.t[0]) if t0 is None else t0
         t1 = float(self.t[-1]) if t1 is None else t1
         return self._window_integral(total, t0, t1)
 
     def component_energy_j(self) -> Dict[str, float]:
-        return {name: float(trapezoid(w, self.t))
+        return {name: float(np.trapezoid(w, self.t))
                 for name, w in self.components.items()}
 
     def scaled(self, factor: float) -> "PowerTrace":

@@ -36,8 +36,11 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig,
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch)
         else:
+            # (B/M, M, ...) then swap: the data-sharded batch axis stays
+            # on the per-microbatch rows, the scanned axis is unsharded
             mb = jax.tree.map(
-                lambda x: x.reshape((M, x.shape[0] // M) + x.shape[1:]),
+                lambda x: jnp.swapaxes(
+                    x.reshape((x.shape[0] // M, M) + x.shape[1:]), 0, 1),
                 batch)
 
             def body(carry, b):

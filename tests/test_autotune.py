@@ -151,6 +151,20 @@ def test_dgemm_model_rejects_nondividing_and_oversized_tiles():
     assert perf == 0.0                               # blows the VMEM budget
 
 
+def test_dslash_space_and_model_offer_only_compiling_t_blocks():
+    """At the thermal half-lattice the v5e compiler takes t_block 1 and 2
+    and refuses 4 (scoped VMEM); space and model agree with it."""
+    from repro.autotune import AnalyticDslashModel
+    from repro.autotune.space import dslash_tile_space
+    half = (16, 32, 32, 8)
+    assert dslash_tile_space(half).axes["t_block"] == (1, 2)
+    model = AnalyticDslashModel(half)
+    assert model.evaluate({"t_block": 4}) == (0.0, float("inf"))
+    assert all(model.evaluate({"t_block": tb})[0] > 0.0 for tb in (1, 2))
+    # a small lattice keeps the whole range, t_block=1 included
+    assert dslash_tile_space((4, 8, 8, 8)).axes["t_block"] == (1, 2, 4)
+
+
 # -- tuned=True consumer paths ------------------------------------------
 
 def test_dgemm_tuned_path_matches_ref(tmp_path):

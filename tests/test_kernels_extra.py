@@ -30,7 +30,6 @@ def test_compressed_pod_mean_on_mesh():
     """int8 cross-pod gradient mean with error feedback converges to the
     true mean over steps (2x2 pod x data CPU device mesh)."""
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.optim.grad_compress import compressed_psum_leaf
     need_devices(4)
     mesh = jax.make_mesh((2, 2), ("pod", "data"))
@@ -40,10 +39,10 @@ def test_compressed_pod_mean_on_mesh():
             # compressed_psum_leaf already returns the cross-pod MEAN
             red, e = compressed_psum_leaf(g_l, e_l, "pod")
             return red, e
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P("pod"), P("pod")),
-                         out_specs=(P("pod"), P("pod")),
-                         check_vma=False)(g, err)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("pod"), P("pod")),
+                             out_specs=(P("pod"), P("pod")),
+                             check_vma=False)(g, err)
 
     rng = np.random.default_rng(0)
     g_true = jnp.asarray(rng.normal(0, 1e-2, (2, 256)), jnp.float32)

@@ -110,10 +110,11 @@ def test_train_step_small_mesh():
     from repro.config import MeshConfig
     from repro.distributed.sharding import (batch_pspecs, named_shardings,
                                             param_pspecs)
+    from repro.launch.mesh import make_mesh_from_config
     need_devices(4)
     cfg = smoke_config("grok-1-314b")
     mesh_cfg = MeshConfig((2, 2), ("data", "model"))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh_from_config(mesh_cfg)
     params = init_params(cfg, jax.random.PRNGKey(0))
     opt = adamw_init(params)
     batch = {"tokens": jnp.zeros((4, 32), jnp.int32),
