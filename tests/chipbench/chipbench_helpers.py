@@ -1,0 +1,67 @@
+"""Shared helpers of the chip benchmark's CPU tests: a checkout in a
+temporary directory with a small cell added by files alone, and a harness
+run on it with the look for a chip patched out."""
+from __future__ import annotations
+
+import json
+import shutil
+import sys
+import time
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[2]
+if str(REPO) not in sys.path:
+    sys.path.insert(0, str(REPO))
+BENCH = REPO / "benchmarks" / "chip"
+SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
+
+
+def small_root(tmp: Path, lattice=(4, 4, 4, 4), t_shards: int = 1,
+               backend: str = "jnp", traffic: str = "heavy",
+               name: str = "small") -> Path:
+    """A copy of the benchmark under ``tmp`` with a configuration
+    ``<name>`` (the thermal one at ``lattice``) and a cell ``<name>.cell``
+    added as new files and entries; no existing file is edited."""
+    root = tmp / "checkout"
+    shutil.copytree(BENCH, root / "benchmarks" / "chip",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    cfg = json.loads((BENCH / "configs" / "thermal_32x8.json").read_text())
+    cfg.update(name=name, lattice=list(lattice), t_shards=t_shards,
+               backend=backend)
+    cfg_file = f"benchmarks/chip/configs/{name}.json"
+    (root / cfg_file).write_text(json.dumps(cfg))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": name, "source": "test", "file": cfg_file,
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": f"{name}.cell", "config": name,
+                              "traffic": traffic, "chips": t_shards,
+                              "why": "test"})
+    for m in spec["per_layer"]:
+        m["workloads"].append(f"{name}.cell")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return root
+
+
+def run_small(root: Path, workload: str, seed: int = 3, seconds: float = 0.0,
+              trace: bool = False):
+    """The harness's run; on the CPU only after ``on_cpu``."""
+    from benchmarks.chip.harness import run
+    return run(root, workload, seed, seconds, trace, time.perf_counter())
+
+
+def on_cpu(monkeypatch):
+    """Let the harness run on the CPU: its look for a chip hands back the
+    CPU devices, the chip's peaks are unknown (the roofline readers then
+    find nothing to read), and its persistent-cache settings stay out of
+    other tests.  Returns what restores the cache setting."""
+    import jax
+    from benchmarks.chip import harness
+    monkeypatch.setattr(harness, "require_chips",
+                        lambda chips: jax.devices()[:chips])
+    monkeypatch.setattr(harness, "load_peaks", lambda root, kind: None)
+    monkeypatch.setattr(
+        "repro.runtime.compile_cache.enable_compile_cache",
+        lambda: "off in tests")
+    before = jax.config.jax_persistent_cache_min_compile_time_secs
+    return lambda: jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", before)

@@ -1,0 +1,77 @@
+"""The harness end to end on the CPU: it refuses to measure without a TPU,
+fails where the system under test is absent, and otherwise drives a whole
+run (set-up, window, check, result line) on a small cell."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from chipbench_helpers import REPO, SPEC, on_cpu, run_small, small_root
+
+
+@pytest.fixture
+def harness_env(monkeypatch):
+    restore = on_cpu(monkeypatch)
+    yield
+    restore()
+
+
+def _command(cwd, workload="thermal_32x8.light"):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, *SPEC["command"][1:], "--workload", workload,
+         "--seed", "3000000019", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def test_refuses_a_platform_other_than_tpu():
+    p = _command(REPO)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "needs a TPU; JAX found cpu" in p.stderr
+
+
+def test_fails_without_the_system_under_test(tmp_path):
+    """A directory that holds only BENCHMARK.json and the benchmark's own
+    paths has no program to measure: non-zero exit and no result."""
+    shutil.copy(REPO / "BENCHMARK.json", tmp_path)
+    for p in SPEC["paths"]:
+        shutil.copytree(REPO / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    p = _command(tmp_path)
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+
+
+def test_small_cell_runs_and_is_correct(tmp_path, harness_env):
+    root = small_root(tmp_path)
+    out = run_small(root, "small.cell", seconds=0.5)
+    assert list(out) == ["correct", "attempted", "failed", "metrics",
+                         "device", "checks"]
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] >= 1
+    assert set(out["metrics"]) == {"solve_s", "setup_s"}
+    assert out["metrics"]["solve_s"]["unit"] == "s"
+    assert 0 < out["metrics"]["solve_s"]["value"]
+    check = out["checks"]["residual_max"]
+    assert check["limit"] == 1e-6 and 0 < check["value"] <= 1e-6
+    assert out["device"]["count"] == 1
+    json.dumps(out)
+
+
+def test_traced_run_reports_per_layer_metrics(tmp_path, harness_env):
+    """With --trace 1 the line carries the per-layer metrics that have
+    something to read: on the CPU the counters, not the device trace."""
+    root = small_root(tmp_path)
+    out = run_small(root, "small.cell", seconds=0.0, trace=True)
+    assert out["correct"] is True
+    assert set(out["metrics"]) == {"inner_cg.ops_per_solve",
+                                   "outer.rounds_per_solve"}
+    assert out["metrics"]["outer.rounds_per_solve"]["value"] >= 1
+    assert "window_s" in out["device"] and "busy_s" in out["device"]
+    assert list(out)[-1] == "checks" and "breakdown" in out
+    assert not (root / ".bench_out" / "trace").exists()
