@@ -13,6 +13,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.lqcd.dirac import wilson_matvec, wilson_matvec_dagger
 
@@ -166,6 +167,17 @@ def _eo_finish(U, U_e, U_o, x_e, b, b_o, kappa):
     return x, jnp.sqrt(_dot(true_r, true_r))
 
 
+def _read(x, what: str, cast=float):
+    """Bring one device value to the host inside an ``lqcd.sync`` span.
+
+    Every blocking readback of the even-odd solve goes through here, so
+    the number of ``lqcd.sync`` spans in a traced solve is its count of
+    host syncs."""
+    with TraceAnnotation("lqcd.sync", what=what):
+        return cast(x)
+
+
+@partial(annotate_function, name="lqcd.solve")
 def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
                     tol: float = 1e-6, max_iters: int = 1000,
                     inner_dtype=None, inner_tol: float = 1e-2,
@@ -194,55 +206,61 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
     kernel on halo-padded blocks.  Back-substitution and the true residual
     stay T-sharded too (no field is gathered onto one device).
     """
-    U_e, U_o, b_e, b_o, b_norm = _eo_setup(U, b)
-    b_norm = float(b_norm)
-    # no low-precision pass gets below its own roundoff; full precision
-    # drives straight to tol in one outer sweep
-    eta = inner_tol if inner_dtype is not None else tol
+    # spans on the profiler's clock: lqcd.setup, one lqcd.round per round
+    # and lqcd.finish never overlap; between them only the loop's own
+    # bookkeeping runs
+    with TraceAnnotation("lqcd.setup"):
+        U_e, U_o, b_e, b_o, b_norm = _eo_setup(U, b)
+        b_norm = _read(b_norm, "b_norm")
+        # no low-precision pass gets below its own roundoff; full
+        # precision drives straight to tol in one outer sweep
+        eta = inner_tol if inner_dtype is not None else tol
 
-    if mesh is not None:
-        from repro.lqcd.eo import eo_unpack
-        from repro.lqcd.multichip import dslash_sharded
-        from repro.lqcd.multichip_eo import ShardedWilsonEO
-        hi = ShardedWilsonEO(U_e, U_o, kappa, mesh, axis_name=axis_name,
-                             overlap=overlap, backend=backend)
-        # the inner CG streams the *rounded* gauge field, like the
-        # single-device normal_lo path
-        lo = hi if inner_dtype is None else ShardedWilsonEO(
-            _round_complex(U_e, inner_dtype), _round_complex(U_o, inner_dtype),
-            kappa, mesh, axis_name=axis_name, overlap=overlap,
-            backend=backend)
-        rhs_e = hi.rhs(b_e, b_o)
+        if mesh is not None:
+            from repro.lqcd.eo import eo_unpack
+            from repro.lqcd.multichip import dslash_sharded
+            from repro.lqcd.multichip_eo import ShardedWilsonEO
+            hi = ShardedWilsonEO(U_e, U_o, kappa, mesh, axis_name=axis_name,
+                                 overlap=overlap, backend=backend)
+            # the inner CG streams the *rounded* gauge field, like the
+            # single-device normal_lo path
+            lo = hi if inner_dtype is None else ShardedWilsonEO(
+                _round_complex(U_e, inner_dtype),
+                _round_complex(U_o, inner_dtype), kappa, mesh,
+                axis_name=axis_name, overlap=overlap, backend=backend)
+            rhs_e = hi.rhs(b_e, b_o)
 
-        def run_round(x_e, r_s, cap):
-            inner = lo.cg_normal(hi.schur_dagger(r_s), tol=eta,
-                                 max_iters=cap, inner_dtype=inner_dtype)
-            x_e = x_e + inner.x
-            r_s = rhs_e - hi.schur(x_e)
-            return x_e, r_s, jnp.sqrt(_dot(r_s, r_s)), inner.iters
+            def run_round(x_e, r_s, cap):
+                inner = lo.cg_normal(hi.schur_dagger(r_s), tol=eta,
+                                     max_iters=cap, inner_dtype=inner_dtype)
+                x_e = x_e + inner.x
+                r_s = rhs_e - hi.schur(x_e)
+                return x_e, r_s, jnp.sqrt(_dot(r_s, r_s)), inner.iters
 
-        def finish(x_e):
-            x = eo_unpack(x_e, hi.reconstruct(x_e, b_o))
-            true_r = b - (x - kappa * dslash_sharded(U, x, mesh, axis_name))
-            return x, jnp.sqrt(_dot(true_r, true_r))
-    else:
-        rhs_e, *U_lo = _eo_system(U_e, U_o, b_e, b_o, kappa,
-                                  inner_dtype=inner_dtype)
+            def finish(x_e):
+                x = eo_unpack(x_e, hi.reconstruct(x_e, b_o))
+                true_r = b - (x - kappa * dslash_sharded(U, x, mesh,
+                                                          axis_name))
+                return x, jnp.sqrt(_dot(true_r, true_r))
+        else:
+            rhs_e, *U_lo = _eo_system(U_e, U_o, b_e, b_o, kappa,
+                                      inner_dtype=inner_dtype)
 
-        def run_round(x_e, r_s, cap):
-            # three programs, not one: XLA would keep the Schur operators'
-            # temporaries beside the inner loop's
-            rhs_n = _eo_defect_rhs(U_e, U_o, r_s, kappa)
-            e, iters = _eo_inner(*U_lo, rhs_n, kappa, eta, cap,
-                                 inner_dtype=inner_dtype)
-            return _eo_update(U_e, U_o, rhs_e, x_e, e, kappa) + (iters,)
+            def run_round(x_e, r_s, cap):
+                # three programs, not one: XLA would keep the Schur
+                # operators' temporaries beside the inner loop's
+                rhs_n = _eo_defect_rhs(U_e, U_o, r_s, kappa)
+                e, iters = _eo_inner(*U_lo, rhs_n, kappa, eta, cap,
+                                     inner_dtype=inner_dtype)
+                return _eo_update(U_e, U_o, rhs_e, x_e, e, kappa) + (iters,)
 
-        def finish(x_e):
-            return _eo_finish(U, U_e, U_o, x_e, b, b_o, kappa)
+            def finish(x_e):
+                return _eo_finish(U, U_e, U_o, x_e, b, b_o, kappa)
 
-    x_e = jnp.zeros_like(rhs_e)
-    r_s = rhs_e                              # Schur-system residual
-    r_norm = float(jnp.sqrt(_dot(r_s, r_s)))
+        x_e = jnp.zeros_like(rhs_e)
+        r_s = rhs_e                          # Schur-system residual
+        r_norm = _read(jnp.sqrt(_dot(r_s, r_s)), "r_norm0")
+
     total_inner = 0
     outer = 0
     while outer < max_outer and total_inner < max_iters:
@@ -254,14 +272,17 @@ def solve_wilson_eo(U: jnp.ndarray, b: jnp.ndarray, kappa: float, *,
         remaining = max_iters - total_inner
         round_cap = (remaining if inner_dtype is None
                      else min(remaining, max(10, max_iters // 5)))
-        x_e, r_s, r_norm, iters = run_round(x_e, r_s, jnp.int32(round_cap))
-        total_inner += int(iters)
-        r_norm = float(r_norm)
+        with TraceAnnotation("lqcd.round", round=outer, cap=round_cap):
+            x_e, r_s, r_norm, iters = run_round(x_e, r_s,
+                                                jnp.int32(round_cap))
+            total_inner += _read(iters, "iters", int)
+            r_norm = _read(r_norm, "r_norm")
         outer += 1
 
-    x, true_norm = finish(x_e)
-    rel = float(true_norm) / max(b_norm, 1e-30)
-    return EOCGResult(x, total_inner, outer, rel, rel <= tol)
+    with TraceAnnotation("lqcd.finish"):
+        x, true_norm = finish(x_e)
+        rel = _read(true_norm, "true_norm") / max(b_norm, 1e-30)
+        return EOCGResult(x, total_inner, outer, rel, rel <= tol)
 
 
 def solve_dirac(U: jnp.ndarray, b: jnp.ndarray, kappa: float, cfg, *,

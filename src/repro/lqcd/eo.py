@@ -35,6 +35,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -147,15 +148,18 @@ def dslash_half(U_out: jnp.ndarray, U_src: jnp.ndarray, psi: jnp.ndarray,
     s_out = jnp.asarray(_sublattice_offset(
         (2 * psi.shape[0],) + psi.shape[1:4], out_parity)[0])
 
-    out = hops_spatial(U_out, U_src, psi, s_out)
+    # named in the compiled program's op metadata, so a device trace can
+    # tell the hop's operations from the rest (docs/solvers.md)
+    with jax.named_scope("lqcd.hop"):
+        out = hops_spatial(U_out, U_src, psi, s_out)
 
-    # t direction: plain rolls (axis 3 of the compact layout)
-    mu = 3
-    psi_f = jnp.roll(psi, -1, axis=mu)
-    psi_b = jnp.roll(psi, 1, axis=mu)
-    u_b = jnp.roll(U_src[mu], 1, axis=mu)
-    out = out + spin(PROJ_M[mu], mv(U_out[mu], psi_f))
-    out = out + spin(PROJ_P[mu], mv_dag(u_b, psi_b))
+        # t direction: plain rolls (axis 3 of the compact layout)
+        mu = 3
+        psi_f = jnp.roll(psi, -1, axis=mu)
+        psi_b = jnp.roll(psi, 1, axis=mu)
+        u_b = jnp.roll(U_src[mu], 1, axis=mu)
+        out = out + spin(PROJ_M[mu], mv(U_out[mu], psi_f))
+        out = out + spin(PROJ_P[mu], mv_dag(u_b, psi_b))
     return out
 
 

@@ -126,24 +126,27 @@ def _half_hop_local(U_out: jnp.ndarray, U_src: jnp.ndarray,
         from_next = jax.lax.ppermute(send_f, axis_name, fwd_perm)
         from_prev = jax.lax.ppermute(send_b, axis_name, bwd_perm)
 
-        # interior: x/y/z hops and the on-shard t hops, while halos fly
-        u_t = U_out[3]
-        u_last = jax.lax.slice_in_dim(u_t, Tl - 1, Tl, axis=T_AX)
-        out = hops_spatial(U_out, U_src, psi, s_out)
-        f_int = spin(PROJ_M[3], mv(
-            jax.lax.slice_in_dim(u_t, 0, Tl - 1, axis=T_AX),
-            jax.lax.slice_in_dim(psi, 1, Tl, axis=T_AX)))
-        b_int = spin(PROJ_P[3], mv_dag(
-            jax.lax.slice_in_dim(U_src[3], 0, Tl - 1, axis=T_AX),
-            jax.lax.slice_in_dim(psi, 0, Tl - 1, axis=T_AX)))
+        with jax.named_scope("lqcd.hop"):
+            # interior: x/y/z hops and the on-shard t hops, while halos fly
+            u_t = U_out[3]
+            u_last = jax.lax.slice_in_dim(u_t, Tl - 1, Tl, axis=T_AX)
+            out = hops_spatial(U_out, U_src, psi, s_out)
+            f_int = spin(PROJ_M[3], mv(
+                jax.lax.slice_in_dim(u_t, 0, Tl - 1, axis=T_AX),
+                jax.lax.slice_in_dim(psi, 1, Tl, axis=T_AX)))
+            b_int = spin(PROJ_P[3], mv_dag(
+                jax.lax.slice_in_dim(U_src[3], 0, Tl - 1, axis=T_AX),
+                jax.lax.slice_in_dim(psi, 0, Tl - 1, axis=T_AX)))
 
-        # boundary rows as the halos land: zero-fill the dropped spin
-        # components and apply the same projector∘link composition as the
-        # interior — the projector annihilates the zero fill exactly
-        f_bnd = spin(PROJ_M[3], mv(u_last, scatter_spin(from_next, 2)))
-        b_bnd = spin(PROJ_P[3], mv_dag(u_prev, scatter_spin(from_prev, 0)))
-        out = out + jnp.concatenate([f_int, f_bnd], axis=T_AX)
-        out = out + jnp.concatenate([b_bnd, b_int], axis=T_AX)
+            # boundary rows as the halos land: zero-fill the dropped spin
+            # components and apply the same projector∘link composition as
+            # the interior — the projector annihilates the zero fill
+            # exactly
+            f_bnd = spin(PROJ_M[3], mv(u_last, scatter_spin(from_next, 2)))
+            b_bnd = spin(PROJ_P[3], mv_dag(u_prev,
+                                           scatter_spin(from_prev, 0)))
+            out = out + jnp.concatenate([f_int, f_bnd], axis=T_AX)
+            out = out + jnp.concatenate([b_bnd, b_int], axis=T_AX)
         return out
 
     # halo-then-compute baseline: full-spinor halos, everything serialized
@@ -155,18 +158,20 @@ def _half_hop_local(U_out: jnp.ndarray, U_src: jnp.ndarray,
     psi, from_next, from_prev, U_out, U_src, u_prev = \
         jax.lax.optimization_barrier(
             (psi, from_next, from_prev, U_out, U_src, u_prev))
-    u_t = U_out[3]
-    out = hops_spatial(U_out, U_src, psi, s_out)
-    psi_f = jnp.concatenate(
-        [jax.lax.slice_in_dim(psi, 1, Tl, axis=T_AX), from_next], axis=T_AX)
-    out = out + spin(PROJ_M[3], mv(u_t, psi_f))
-    psi_b = jnp.concatenate(
-        [from_prev, jax.lax.slice_in_dim(psi, 0, Tl - 1, axis=T_AX)],
-        axis=T_AX)
-    u_b = jnp.concatenate(
-        [u_prev, jax.lax.slice_in_dim(U_src[3], 0, Tl - 1, axis=T_AX)],
-        axis=T_AX)
-    out = out + spin(PROJ_P[3], mv_dag(u_b, psi_b))
+    with jax.named_scope("lqcd.hop"):
+        u_t = U_out[3]
+        out = hops_spatial(U_out, U_src, psi, s_out)
+        psi_f = jnp.concatenate(
+            [jax.lax.slice_in_dim(psi, 1, Tl, axis=T_AX), from_next],
+            axis=T_AX)
+        out = out + spin(PROJ_M[3], mv(u_t, psi_f))
+        psi_b = jnp.concatenate(
+            [from_prev, jax.lax.slice_in_dim(psi, 0, Tl - 1, axis=T_AX)],
+            axis=T_AX)
+        u_b = jnp.concatenate(
+            [u_prev, jax.lax.slice_in_dim(U_src[3], 0, Tl - 1, axis=T_AX)],
+            axis=T_AX)
+        out = out + spin(PROJ_P[3], mv_dag(u_b, psi_b))
     return out
 
 
@@ -194,9 +199,10 @@ def _half_hop_pallas_local(U_out_pad: jnp.ndarray, U_src_pad: jnp.ndarray,
     psi_pad = jnp.concatenate(
         [scatter_spin(from_prev, 0), psi, scatter_spin(from_next, 2)],
         axis=T_AX)
-    out_pad = dslash_half_split(U_out_pad, U_src_pad, psi_pad,
-                                src_parity_eff, t_block=t_block,
-                                interpret=interpret)
+    with jax.named_scope("lqcd.hop"):
+        out_pad = dslash_half_split(U_out_pad, U_src_pad, psi_pad,
+                                    src_parity_eff, t_block=t_block,
+                                    interpret=interpret)
     return jax.lax.slice_in_dim(out_pad, 1, Tl + 1, axis=T_AX)
 
 
@@ -299,7 +305,8 @@ class ShardedWilsonEO:
         return fn
 
     def _vec_fn(self, kind: str):
-        """Jitted shard_map for one of the vector→vector operators."""
+        """Jitted shard_map for one of the vector→vector operators; its
+        program is named ``jit_eo_<kind>`` in a trace."""
         def build():
             ng = len(self._gauge_specs)
 
@@ -319,6 +326,7 @@ class ShardedWilsonEO:
                 av = self._schur_from_hop(hop, v)
                 return g5(self._schur_from_hop(hop, g5(av)))
 
+            body.__name__ = body.__qualname__ = f"eo_{kind}"
             return jax.jit(self._shmap(
                 body, in_specs=self._gauge_specs + (self._p_spec,),
                 out_specs=self._p_spec))
