@@ -179,8 +179,9 @@ def check(solves: List[Solve], U, sources, kappa, limit: float, device):
     plain reference on one device: (max residual, solves over the limit)."""
     import jax
     from benchmarks.chip.reference import relative_residual
-    # the solutions go to the host first: the window's device copies
-    # (sharded, in the program's padded layout) are freed before the
+    # every solution but the last is on the host already (``run`` moves
+    # each as the window runs); the last follows, and its device copy
+    # (sharded, in the program's padded layout) is freed before the
     # reference needs one chip's memory
     xs = jax.device_get([s.x for s in solves])
     for s in solves:
@@ -222,6 +223,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         t_start: float) -> Dict[str, Any]:
     """One run; returns the result line as a dict (``checks`` last)."""
     import jax
+    import numpy as np
     from repro.runtime.compile_cache import enable_compile_cache
     from benchmarks.chip import fields
 
@@ -259,7 +261,14 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         t = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.solve"):
             res = solver(U, sources[i])
-        solves.append(Solve(i, time.perf_counter() - t, int(res.iters),
+        took = time.perf_counter() - t
+        # the window's solutions leave the device as it runs: this one's
+        # copy starts now, and the last one's, which landed while this
+        # solve ran, takes its place, so at most two stay on the chip
+        res.x.copy_to_host_async()
+        if solves:
+            solves[-1].x = np.asarray(solves[-1].x)
+        solves.append(Solve(i, took, int(res.iters),
                             int(res.outer_iters), res.x))
 
     before = compiles.snapshot()

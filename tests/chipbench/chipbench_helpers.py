@@ -8,6 +8,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import SimpleNamespace
 
 REPO = Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
@@ -65,3 +66,23 @@ def on_cpu(monkeypatch):
     before = jax.config.jax_persistent_cache_min_compile_time_secs
     return lambda: jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", before)
+
+
+def close_window_after(monkeypatch, n: int):
+    """Make the harness's window close after exactly ``n`` solves, however
+    long each takes: its clock jumps a day ahead once the ``n``-th
+    ``Solve`` is made.  Run with ``seconds`` under a day.  Returns the
+    window's ``Solve``s, in the order they are made."""
+    from benchmarks.chip import harness
+    made = []
+
+    class Counted(harness.Solve):
+        def __init__(self, *args, **kw):
+            super().__init__(*args, **kw)
+            made.append(self)
+
+    def clock():
+        return time.perf_counter() + (86400.0 if len(made) >= n else 0.0)
+    monkeypatch.setattr(harness, "Solve", Counted)
+    monkeypatch.setattr(harness, "time", SimpleNamespace(perf_counter=clock))
+    return made

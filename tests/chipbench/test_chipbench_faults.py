@@ -3,15 +3,17 @@
 Each test drives a whole run of a small cell on the CPU with the solver
 broken underneath the harness, and sees ``correct`` come out false: a
 solve that hands back its starting state, the odd half of the lattice
-left out of the answer, an answer altered where it is produced, and the
-control (the reference solve one precision lower) in the solver's place.
+left out of the answer, an answer altered where it is produced (every
+answer, or the window's first alone), and the control (the reference
+solve one precision lower) in the solver's place.
 The exchange between chips left out is in ``test_chipbench_sharded.py``."""
 from types import SimpleNamespace
 
 import jax.numpy as jnp
 import pytest
 
-from chipbench_helpers import on_cpu, run_small, small_root
+from chipbench_helpers import (close_window_after, on_cpu, run_small,
+                               small_root)
 
 import repro.lqcd.cg as cg
 
@@ -54,6 +56,26 @@ def test_broken_solution_is_not_correct(tmp_path, monkeypatch, harness_env,
     out = run_small(root, "small.cell", seconds=0.0)
     assert out["correct"] is False
     assert out["failed"] == out["attempted"] >= 1
+    assert out["checks"]["residual_max"]["value"] > 1e-6
+
+
+def test_first_solution_of_the_window_altered_is_not_correct(
+        tmp_path, monkeypatch, harness_env):
+    """Only the window's first answer is altered; it has left the device
+    long before the check, and its host copy still carries the fault
+    there: one failed solve of four."""
+    close_window_after(monkeypatch, 4)
+    calls = []
+
+    def first_of_window(x, b):
+        calls.append(None)
+        # the first call is the warm-up solve, outside the window
+        return _one_entry_altered(x, b) if len(calls) == 2 else x
+    root = small_root(tmp_path)
+    _break_solution(monkeypatch, first_of_window)
+    out = run_small(root, "small.cell", seconds=3600.0)
+    assert out["correct"] is False
+    assert out["attempted"] == 4 and out["failed"] == 1
     assert out["checks"]["residual_max"]["value"] > 1e-6
 
 

@@ -9,7 +9,8 @@ import sys
 
 import pytest
 
-from chipbench_helpers import REPO, SPEC, on_cpu, run_small, small_root
+from chipbench_helpers import (REPO, SPEC, close_window_after, on_cpu,
+                               run_small, small_root)
 
 
 @pytest.fixture
@@ -75,3 +76,35 @@ def test_traced_run_reports_per_layer_metrics(tmp_path, harness_env):
     assert "window_s" in out["device"] and "busy_s" in out["device"]
     assert list(out)[-1] == "checks" and "breakdown" in out
     assert not (root / ".bench_out" / "trace").exists()
+
+
+def test_window_keeps_at_most_two_solutions_on_the_device(
+        tmp_path, monkeypatch, harness_env):
+    """Each solution of the window leaves the device while the next solve
+    runs: when a solve returns, it and at most the one before it are
+    device arrays, whatever the window's length, and every solve of the
+    window still reaches the check."""
+    import jax
+    import repro.lqcd.cg as cg
+    from benchmarks.chip import harness
+    made = close_window_after(monkeypatch, 5)
+    real_solve, real_check = cg.solve_dirac, harness.check
+    on_device = []            # device solutions when each solve returns
+    checked = []              # (solve, on the device) as the check gets it
+
+    def solve(*args, **kw):
+        res = real_solve(*args, **kw)
+        on_device.append(1 + sum(isinstance(s.x, jax.Array) for s in made))
+        return res
+
+    def check(solves, *args, **kw):
+        checked.extend((s, isinstance(s.x, jax.Array)) for s in solves)
+        return real_check(solves, *args, **kw)
+    monkeypatch.setattr(cg, "solve_dirac", solve)
+    monkeypatch.setattr(harness, "check", check)
+    out = run_small(small_root(tmp_path), "small.cell", seconds=3600.0)
+    assert out["correct"] is True and out["attempted"] == 5
+    # the warm-up solve, then the window's five
+    assert on_device == [1, 1, 2, 2, 2, 2]
+    assert [id(s) for s, _ in checked] == [id(s) for s in made]
+    assert [on for _, on in checked] == [False] * 4 + [True]
