@@ -116,13 +116,21 @@ def _eo_setup(U: jnp.ndarray, b: jnp.ndarray):
     return U_e, U_o, eo_pack(b, 0), eo_pack(b, 1), jnp.sqrt(_dot(b, b))
 
 
+def _plane_dtype(inner_dtype):
+    """What the inner CG stores its operator's fields in."""
+    return jnp.float32 if inner_dtype is None else inner_dtype
+
+
 @partial(jax.jit, static_argnames=("inner_dtype",))
 def _eo_system(U_e, U_o, b_e, b_o, kappa, *, inner_dtype):
     """Schur right-hand side b_e + κ D_eo b_o, and the gauge halves the
-    inner CG streams (rounded through ``inner_dtype``)."""
+    inner CG streams: real planes stored in ``inner_dtype``
+    (:mod:`repro.lqcd.eo_planes`)."""
     from repro.lqcd.eo import eo_rhs
+    from repro.lqcd.eo_planes import link_planes
+    store = _plane_dtype(inner_dtype)
     return (eo_rhs(U_e, U_o, b_e, b_o, kappa),
-            _round_complex(U_e, inner_dtype), _round_complex(U_o, inner_dtype))
+            link_planes(U_e, store), link_planes(U_o, store))
 
 
 @jax.jit
@@ -134,18 +142,19 @@ def _eo_defect_rhs(U_e, U_o, r_s, kappa):
 
 @partial(jax.jit, static_argnames=("inner_dtype",))
 def _eo_inner(U_e, U_o, rhs_n, kappa, eta, cap, *, inner_dtype):
-    """Inner CG on A†A e = rhs_n, fields rounded through ``inner_dtype``
-    (``U_e``/``U_o`` already rounded), at most ``cap`` normal ops."""
-    from repro.lqcd.eo import schur_matvec, schur_matvec_dagger
+    """Inner CG on A†A e = rhs_n, at most ``cap`` normal ops, on real
+    planes (:mod:`repro.lqcd.eo_planes`): the links (``U_e``/``U_o``, as
+    ``_eo_system`` makes them) and the normal op's input and outputs are
+    stored in ``inner_dtype``, the CG vectors in float32.  ``rhs_n`` and
+    the correction come and go in the complex layout."""
+    from repro.lqcd import eo_planes
 
-    def normal(v):
-        v = _round_complex(v, inner_dtype)
-        av = _round_complex(schur_matvec(U_e, U_o, v, kappa), inner_dtype)
-        return _round_complex(schur_matvec_dagger(U_e, U_o, av, kappa),
-                              inner_dtype)
-
-    inner = cg_solve(normal, rhs_n, tol=eta, max_iters=cap)
-    return inner.x, inner.iters
+    xh = rhs_n.shape[0]
+    store = _plane_dtype(inner_dtype)
+    U_e, U_o = (eo_planes.link_planes(U, store) for U in (U_e, U_o))
+    inner = cg_solve(lambda v: eo_planes.normal(U_e, U_o, v, kappa, xh),
+                     eo_planes.spinor_planes(rhs_n), tol=eta, max_iters=cap)
+    return eo_planes.spinor_complex(inner.x, xh), inner.iters
 
 
 @jax.jit

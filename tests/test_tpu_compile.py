@@ -106,3 +106,23 @@ def test_blocked_lu_compiles_at_8192(one_chip):
     compiled = jax.jit(lambda m: blocked_lu(m, 256)).lower(a).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
+
+
+def test_planes_inner_cg_compiles_at_thermal(one_chip):
+    """The single-device inner CG on bf16 planes, the thermal cell's hot
+    loop: its temporaries fit in a fraction of what the complex layout's
+    padded fields needed (1.73 GB for this program)."""
+    from repro.lqcd import cg
+    X, Y, Z, T = THERMAL
+    links = jax.ShapeDtypeStruct((2, 4, 3, 3, T, Z, Y * X // 2),
+                                 jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((X // 2, Y, Z, T, 4, 3), C64,
+                               sharding=one_chip)
+
+    def scalar(dtype):
+        return jax.ShapeDtypeStruct((), dtype, sharding=one_chip)
+
+    compiled = cg._eo_inner.lower(
+        links, links, rhs, scalar(jnp.float32), scalar(jnp.float32),
+        scalar(jnp.int32), inner_dtype=jnp.bfloat16).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
