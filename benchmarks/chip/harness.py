@@ -1,23 +1,41 @@
-"""One run of one cell: set-up, a closed loop of Wilson-Dirac solves for a
-fixed number of seconds, the check against the plain reference, and the
-result line.
+"""One run of one cell: set-up, a closed loop of solves of the
+configuration's system for a fixed number of seconds, the check of every
+answer against the system's plain reference, and the result line.
 
 Everything about a cell is data found by name: the cell in
 ``BENCHMARK.json``, its configuration in the file that entry names, its
-traffic mix in ``benchmarks/chip/traffic/<traffic>.json``, each per-layer
-metric in ``benchmarks/chip/metrics/<metric>.py`` (a ``read(ctx)`` that
-returns a number, or None where it finds nothing to read), and the chip's
-peaks in ``benchmarks/chip/peaks.json`` under its ``device_kind``.
+traffic mix in ``benchmarks/chip/traffic/<traffic>.json``, its system
+under test in ``benchmarks/chip/systems/<system>.py`` (the
+configuration's ``system``), each per-layer metric in
+``benchmarks/chip/metrics/<metric>.py`` (a ``read(ctx)`` that returns a
+number, or None where it finds nothing to read), and the chip's peaks in
+``benchmarks/chip/peaks.json`` under its ``device_kind``.
+
+A system module provides:
+
+- ``make_inputs(seed, config, traffic, devices)`` -> ``(shared,
+  requests)``: the state every solve shares and the requests the window
+  cycles through, made on the device from the seed;
+- ``Solver(config, traffic, devices)``, whose ``warm_up(shared, request)``
+  readies every program a solve runs and returns a line for the log, and
+  whose call ``(shared, request)`` returns ``(answer, counters)`` once the
+  answer is on the device: a JAX array, which the harness moves to the
+  host as the window runs, and a dict of the numbers the per-layer
+  readers take from ``Solve.counters``;
+- ``check(solves, shared, requests, config, traffic, device)`` ->
+  ``(failed, checks)``: every answer of the window judged by the system's
+  plain reference, the number of answers over their limit, and each
+  number compared, by name, as ``{"value": v, "limit": l}``;
+- ``control(shared, request, config, traffic)``: the answer the control
+  gives in the solver's place (``control.py``).
 """
 from __future__ import annotations
 
 import importlib.util
 import json
-import math
 import shutil
 import sys
 import time
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Dict, List, Optional
@@ -61,14 +79,25 @@ def load_cell(root: Path, name: str) -> Cell:
                 _for_cell(spec["per_layer"], name))
 
 
-def load_reader(root: Path, metric: str):
-    """The ``read`` function of ``metrics/<metric>.py``."""
-    path = root / DATA / "metrics" / f"{metric}.py"
+def _load_module(root: Path, kind: str, name: str):
+    path = root / DATA / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        "benchmarks.chip.metrics." + metric.replace(".", "_"), path)
+        f"benchmarks.chip.{kind}.{name.replace('.', '_')}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(root: Path, metric: str):
+    """The ``read`` function of ``metrics/<metric>.py``."""
+    return _load_module(root, "metrics", metric).read
+
+
+def load_system(root: Path, name: str):
+    """The system module ``systems/<name>.py``."""
+    if not (root / DATA / "systems" / f"{name}.py").is_file():
+        raise BenchError(f"no system {name!r} under {DATA / 'systems'}")
+    return _load_module(root, "systems", name)
 
 
 def load_peaks(root: Path, kind: str) -> Dict[str, Any]:
@@ -94,11 +123,10 @@ def require_chips(chips: int):
 @dataclass
 class Solve:
     """One completed solve of the window."""
-    source: int
+    source: int                     # index of its request
     seconds: float
-    iters: int
-    outer_iters: int
-    x: Any = field(repr=False)
+    counters: Dict[str, Any]        # as the system's solver reports them
+    x: Any = field(repr=False)      # the answer
 
 
 @dataclass
@@ -138,64 +166,6 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-class Solver:
-    """The system under test: ``repro.lqcd.cg.solve_dirac`` as the cell's
-    configuration states it."""
-
-    def __init__(self, config: Dict[str, Any], kappa: float):
-        from repro.config import SolverConfig
-        from repro.distributed.sharding import lattice_mesh
-        self.kappa = kappa
-        self.cfg = SolverConfig(**config["solver"])
-        shards = int(config["t_shards"])
-        self.mesh = None
-        if shards > 1:
-            self.mesh = lattice_mesh(config["lattice"][3], shards)
-            if self.mesh.size != shards:
-                raise BenchError(f"mesh of {self.mesh.size} devices, "
-                                 f"configuration asks for {shards}")
-        self.kw = dict(mesh=self.mesh, backend=config["backend"],
-                       overlap=bool(config["overlap"]))
-
-    def shardings(self):
-        """(gauge, spinor) placements: T-sharded over the mesh, or None."""
-        if self.mesh is None:
-            return None, None
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        t = self.mesh.axis_names[0]
-        return (NamedSharding(self.mesh, P(None, None, None, None, t)),
-                NamedSharding(self.mesh, P(None, None, None, t)))
-
-    def __call__(self, U, b):
-        import jax
-        from repro.lqcd import cg
-        res = cg.solve_dirac(U, b, self.kappa, self.cfg, **self.kw)
-        jax.block_until_ready(res.x)
-        return res
-
-
-def check(solves: List[Solve], U, sources, kappa, limit: float, device):
-    """The true relative residual of every solve of the window, by the
-    plain reference on one device: (max residual, solves over the limit)."""
-    import jax
-    from benchmarks.chip.reference import relative_residual
-    # every solution but the last is on the host already (``run`` moves
-    # each as the window runs); the last follows, and its device copy
-    # (sharded, in the program's padded layout) is freed before the
-    # reference needs one chip's memory
-    xs = jax.device_get([s.x for s in solves])
-    for s in solves:
-        s.x = None
-    U1 = jax.device_put(U, device)
-    rs = []
-    for s, x in zip(solves, xs):
-        x, b = jax.device_put((x, sources[s.source]), device)
-        rs.append(float(relative_residual(U1, x, b, kappa)))
-    failed = sum(not r <= limit for r in rs)          # NaN fails too
-    worst = float("nan") if any(math.isnan(r) for r in rs) else max(rs)
-    return worst, failed
-
-
 def _traced(root: Path, solve_next, devices):
     """The window's first ``TRACE_SOLVES`` solves under the profiler, and
     the reduced trace.  A short window: the trace of one solve holds some
@@ -225,9 +195,9 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
     from repro.runtime.compile_cache import enable_compile_cache
-    from benchmarks.chip import fields
 
     cell = load_cell(root, workload)
+    system = load_system(root, cell.config["system"])
     devices = require_chips(cell.chips)
     kind = devices[0].device_kind
     peaks = load_peaks(root, kind)
@@ -239,37 +209,32 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         f"compile cache {cache}")
 
     config, traffic = cell.config, cell.traffic
-    kappa = float(traffic["kappa"])
-    solver = Solver(config, kappa)
+    solver = system.Solver(config, traffic, devices)
     t = time.perf_counter()
-    U, sources = fields.make_inputs(seed, config["lattice"], traffic,
-                                    *solver.shardings())
+    shared, requests = system.make_inputs(seed, config, traffic, devices)
     log(f"[setup] JAX up and the cache set in {t - t_start:.2f} s; inputs "
         f"made in {time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
-    warm = solver(U, sources[0])
-    log(f"[setup] warm-up solve: {warm.iters}+{warm.outer_iters} ops, "
-        f"residual {warm.rel_residual:.3e}, {time.perf_counter() - t:.2f} s; "
+    warm = solver.warm_up(shared, requests[0])
+    log(f"[setup] warm-up: {warm}, {time.perf_counter() - t:.2f} s; "
         f"programs compiled or loaded so far: {compiles.snapshot()[0]} "
         f"({compiles.snapshot()[1]} from the cache)")
-    del warm
 
     solves: List[Solve] = []
 
     def solve_next():
-        i = len(solves) % len(sources)
+        i = len(solves) % len(requests)
         t = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.solve"):
-            res = solver(U, sources[i])
+            x, counters = solver(shared, requests[i])
         took = time.perf_counter() - t
-        # the window's solutions leave the device as it runs: this one's
+        # the window's answers leave the device as it runs: this one's
         # copy starts now, and the last one's, which landed while this
         # solve ran, takes its place, so at most two stay on the chip
-        res.x.copy_to_host_async()
+        x.copy_to_host_async()
         if solves:
             solves[-1].x = np.asarray(solves[-1].x)
-        solves.append(Solve(i, took, int(res.iters),
-                            int(res.outer_iters), res.x))
+        solves.append(Solve(i, took, counters, x))
 
     before = compiles.snapshot()
     t0 = time.perf_counter()
@@ -289,8 +254,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     log("[window] seconds per solve: "
         + " ".join(f"{s.seconds:.3f}" for s in solves))
 
-    limit = float(config["solver"]["tol"])
-    worst, failed = check(solves, U, sources, kappa, limit, devices[0])
+    failed, checks = system.check(solves, shared, requests, config, traffic,
+                                  devices[0])
     correct = failed == 0
 
     metrics: Dict[str, Dict[str, Any]] = {}
@@ -317,5 +282,5 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         device["window_s"] = tr.window_s
         out["breakdown"] = {"device_ops": tr.top_ops(),
                             "idle_gaps": tr.idle_by_host()}
-    out["checks"] = {"residual_max": {"value": worst, "limit": limit}}
+    out["checks"] = checks
     return out

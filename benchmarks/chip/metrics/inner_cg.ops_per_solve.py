@@ -5,4 +5,4 @@ as the solver reports them (``EOCGResult.iters``), over the window."""
 def read(ctx):
     if not ctx.solves:
         return None
-    return sum(s.iters for s in ctx.solves) / len(ctx.solves)
+    return sum(s.counters["iters"] for s in ctx.solves) / len(ctx.solves)

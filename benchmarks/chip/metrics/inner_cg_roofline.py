@@ -22,8 +22,8 @@ def read(ctx):
     if busy <= 0:
         return None
     lat, prec = ctx.cell.config["lattice"], ctx.cell.config["precision"]
-    total = work.inner_cg(lat, sum(s.iters for s in ctx.traced),
-                          prec["inner"])
+    iters = sum(s.counters["iters"] for s in ctx.traced)
+    total = work.inner_cg(lat, iters, prec["inner"])
     least = total.seconds_at(ctx.chips * ctx.peaks["bf16_flops_per_s"],
                              ctx.chips * ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least / busy

@@ -5,4 +5,5 @@ loop in ``solve_wilson_eo`` per solve (``EOCGResult.outer_iters``)."""
 def read(ctx):
     if not ctx.solves:
         return None
-    return sum(s.outer_iters for s in ctx.solves) / len(ctx.solves)
+    return (sum(s.counters["outer_iters"] for s in ctx.solves)
+            / len(ctx.solves))

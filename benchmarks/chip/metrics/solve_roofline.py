@@ -14,7 +14,8 @@ def read(ctx):
     lat, prec = ctx.cell.config["lattice"], ctx.cell.config["precision"]
     total = work.Work(0.0, 0.0)
     for s in ctx.solves:
-        total = total + work.solve(lat, s.iters, s.outer_iters,
+        total = total + work.solve(lat, s.counters["iters"],
+                                   s.counters["outer_iters"],
                                    prec["inner"], prec["outer"])
     least = total.seconds_at(ctx.chips * ctx.peaks["bf16_flops_per_s"],
                              ctx.chips * ctx.peaks["hbm_bytes_per_s"])

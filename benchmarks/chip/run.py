@@ -1,13 +1,15 @@
-"""The chip benchmark: seconds per Wilson-Dirac solve, on a TPU only.
+"""The chip benchmark: seconds per solve of the configuration's system, on
+a TPU only.
 
     python3 benchmarks/chip/run.py --workload <cell> --seed <n> \
         --seconds <s> --trace <0|1>
 
 Run from the root of a checkout.  One process sets up the cell named in
-``BENCHMARK.json`` (fields from ``--seed`` on the device, one warm-up
-solve, programs from the persistent compile cache), runs a closed loop of
-solves until ``--seconds`` have passed, checks every solve of the window
-against the plain reference, and prints one JSON line last on standard
+``BENCHMARK.json`` (the system its configuration names, inputs from
+``--seed`` on the device, every program warmed up or loaded from the
+persistent compile cache), runs a closed loop of solves until
+``--seconds`` have passed, checks every solve of the window against the
+system's plain reference, and prints one JSON line last on standard
 output.  ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
 traces the window and reports its per-layer metrics.  The numbers compared
 for ``correct`` come last on standard error, each beside its limit.  It

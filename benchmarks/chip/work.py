@@ -1,6 +1,7 @@
-"""The work a solve needs: operations and bytes, from the lattice, the
-precisions the configuration declares and the iteration counts the
-solver reports.  Never from what the program happens to stream: a program
+"""The work a solve needs.  For HPL, HPL's own operation count
+(``hpl``).  For the Wilson-Dirac solve: operations and bytes, from the
+lattice, the precisions the configuration declares and the iteration
+counts the solver reports.  Never from what the program happens to stream: a program
 that stores its fields wider than declared reads as a lower share, and one
 that stores them as declared can reach, never pass, its roofline.
 
@@ -97,3 +98,9 @@ def solve(lattice, iters: float, rounds: float, inner_dtype: str,
     fixed = _work(lattice, SOLVE_REALS, SOLVE_FLOPS, outer_dtype)
     return (inner_cg(lattice, iters, inner_dtype)
             + Work(outer.flops * rounds, outer.bytes * rounds) + fixed)
+
+
+def hpl(n: int) -> float:
+    """HPL's operation count of one solve of order ``n``: 2/3 n^3 + 3/2 n^2
+    (HPL 2.3's own, in ``HPL_pdtest``), factor and solve."""
+    return 2.0 / 3.0 * n ** 3 + 1.5 * n ** 2

@@ -17,30 +17,65 @@ BENCH = REPO / "benchmarks" / "chip"
 SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
-def small_root(tmp: Path, lattice=(4, 4, 4, 4), t_shards: int = 1,
-               backend: str = "jnp", traffic: str = "heavy",
-               name: str = "small") -> Path:
-    """A copy of the benchmark under ``tmp`` with a configuration
-    ``<name>`` (the thermal one at ``lattice``) and a cell ``<name>.cell``
-    added as new files and entries; no existing file is edited."""
+def _root_with_cell(tmp: Path, base: str, traffic: str, chips: int,
+                    **changes) -> Path:
+    """A copy of the benchmark under ``tmp`` with the configuration
+    ``base`` changed by ``changes`` (``name`` among them) added as a new
+    file, and a cell ``<name>.cell`` on ``traffic`` that joins the
+    per-layer metrics of ``base``'s cell; no existing file is edited."""
     root = tmp / "checkout"
     shutil.copytree(BENCH, root / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    cfg = json.loads((BENCH / "configs" / "thermal_32x8.json").read_text())
-    cfg.update(name=name, lattice=list(lattice), t_shards=t_shards,
-               backend=backend)
+    cfg = json.loads((BENCH / "configs" / f"{base}.json").read_text())
+    cfg.update(changes)
+    name = cfg["name"]
     cfg_file = f"benchmarks/chip/configs/{name}.json"
     (root / cfg_file).write_text(json.dumps(cfg))
     spec = json.loads(json.dumps(SPEC))
     spec["configs"].append({"name": name, "source": "test", "file": cfg_file,
-                            "reduced": [], "why": "test"})
+                            "reduced": cfg["reduced"], "why": "test"})
     spec["workloads"].append({"name": f"{name}.cell", "config": name,
-                              "traffic": traffic, "chips": t_shards,
+                              "traffic": traffic, "chips": chips,
                               "why": "test"})
+    like = next(w["name"] for w in SPEC["workloads"] if w["config"] == base)
     for m in spec["per_layer"]:
-        m["workloads"].append(f"{name}.cell")
+        if like in m["workloads"]:
+            m["workloads"].append(f"{name}.cell")
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root
+
+
+def small_root(tmp: Path, lattice=(4, 4, 4, 4), t_shards: int = 1,
+               traffic: str = "heavy") -> Path:
+    """A copy of the benchmark with the thermal configuration at
+    ``lattice`` as ``small`` and a cell ``small.cell``."""
+    return _root_with_cell(tmp, "thermal_32x8", traffic, t_shards,
+                           name="small", lattice=list(lattice),
+                           t_shards=t_shards, backend="jnp")
+
+
+def small_hpl_root(tmp: Path) -> Path:
+    """A copy of the benchmark with the HPL configuration at N = 256 in
+    blocks of 32 as ``small_hpl`` and a cell ``small_hpl.cell``.  HPL's
+    scaled residual falls slowly as N grows (a float32 LU reads about
+    1e-2 at N = 256 and a few 1e-3 at 2048), so the small configuration
+    states a limit of its own, 0.1; the bf16-product control reads about
+    100 at N = 256."""
+    return _root_with_cell(tmp, "hpl_n8192", "factor", 1, name="small_hpl",
+                           n=256, nb=32, scaled_residual_limit=0.1)
+
+
+def patch_system(monkeypatch, patch):
+    """Apply ``patch(monkeypatch, module)`` to every system module the
+    harness loads from now on."""
+    from benchmarks.chip import harness
+    real = harness.load_system
+
+    def load(root, name):
+        module = real(root, name)
+        patch(monkeypatch, module)
+        return module
+    monkeypatch.setattr(harness, "load_system", load)
 
 
 def run_small(root: Path, workload: str, seed: int = 3, seconds: float = 0.0,

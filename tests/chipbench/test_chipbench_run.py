@@ -10,7 +10,7 @@ import sys
 import pytest
 
 from chipbench_helpers import (REPO, SPEC, close_window_after, on_cpu,
-                               run_small, small_root)
+                               patch_system, run_small, small_root)
 
 
 @pytest.fixture
@@ -86,9 +86,8 @@ def test_window_keeps_at_most_two_solutions_on_the_device(
     window still reaches the check."""
     import jax
     import repro.lqcd.cg as cg
-    from benchmarks.chip import harness
     made = close_window_after(monkeypatch, 5)
-    real_solve, real_check = cg.solve_dirac, harness.check
+    real_solve = cg.solve_dirac
     on_device = []            # device solutions when each solve returns
     checked = []              # (solve, on the device) as the check gets it
 
@@ -97,11 +96,15 @@ def test_window_keeps_at_most_two_solutions_on_the_device(
         on_device.append(1 + sum(isinstance(s.x, jax.Array) for s in made))
         return res
 
-    def check(solves, *args, **kw):
-        checked.extend((s, isinstance(s.x, jax.Array)) for s in solves)
-        return real_check(solves, *args, **kw)
+    def watch_check(monkeypatch, system):
+        real_check = system.check
+
+        def check(solves, *args, **kw):
+            checked.extend((s, isinstance(s.x, jax.Array)) for s in solves)
+            return real_check(solves, *args, **kw)
+        monkeypatch.setattr(system, "check", check)
     monkeypatch.setattr(cg, "solve_dirac", solve)
-    monkeypatch.setattr(harness, "check", check)
+    patch_system(monkeypatch, watch_check)
     out = run_small(small_root(tmp_path), "small.cell", seconds=3600.0)
     assert out["correct"] is True and out["attempted"] == 5
     # the warm-up solve, then the window's five

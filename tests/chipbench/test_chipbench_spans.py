@@ -61,7 +61,7 @@ DEV1 = [("fusion.1", 0, 30), ("fusion.2", 50, 100)]
 def _ctx(profile, devices=None):
     tr = T.reduce_profile(profile, devices=devices)
     cell = harness.load_cell(REPO, "thermal_32x8.light")
-    solves = [harness.Solve(0, 1.0, 20, 3, None)]
+    solves = [harness.Solve(0, 1.0, {"iters": 20, "outer_iters": 3}, None)]
     return harness.Context(cell, solves, solves, tr, None, len(tr.devices))
 
 

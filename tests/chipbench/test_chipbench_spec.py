@@ -54,25 +54,36 @@ def test_cells_and_configurations_load_by_name():
     assert used == {c["name"] for c in SPEC["configs"]}
     files = [c["file"] for c in SPEC["configs"]]
     assert len(files) == len(set(files))
+    assert len({(c["source"], tuple(c["reduced"]))
+                for c in SPEC["configs"]}) == len(SPEC["configs"])
     four = sum(w["chips"] == 4 for w in SPEC["workloads"])
     assert four <= max(1, len(SPEC["workloads"]) // 2)
     for w in SPEC["workloads"]:
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
         cell = harness.load_cell(REPO, w["name"])
         assert cell.config["name"] == w["config"]
-        assert cell.chips == cell.config["t_shards"] == w["chips"]
-        assert cell.traffic["kappa"] > 0
         assert cell.end_to_end and cell.per_layer
         assert {m["name"] for m in cell.end_to_end} >= {"setup_s", "solve_s"}
+        if cell.config["system"] == "lqcd_wilson":
+            assert cell.chips == cell.config["t_shards"] == w["chips"]
+            assert cell.traffic["kappa"] > 0
     for c in SPEC["configs"]:
         cfg = json.loads((REPO / c["file"]).read_text())
         assert c["file"].startswith("benchmarks/chip/")
-        assert cfg["reduced"] == c["reduced"] == []
-        assert cfg["precision"] == {"outer": "float32", "inner": "bfloat16"}
+        assert cfg["reduced"] == c["reduced"]
+        if cfg["system"] == "lqcd_wilson":
+            assert cfg["reduced"] == []
+            assert cfg["precision"] == {"outer": "float32",
+                                        "inner": "bfloat16"}
+        else:
+            assert cfg["system"] == "hpl" and cfg["reduced"] == ["n"]
+            assert cfg["precision"] == {"storage": "float32",
+                                        "products": "float32"}
+            assert cfg["n"] % cfg["nb"] == 0
 
 
 def test_every_metric_has_a_reader():
-    """Each per-layer metric, traffic mix and configuration in
+    """Each per-layer metric, traffic mix, configuration and system in
     BENCHMARK.json has its file; the files of the four-chip cell, which
     waits for a later benchmark PR, load as well."""
     readers = {p.stem for p in (BENCH / "metrics").glob("*.py")}
@@ -83,11 +94,32 @@ def test_every_metric_has_a_reader():
     assert {w["traffic"] for w in SPEC["workloads"]} <= traffic
     for name in traffic:
         t = json.loads((BENCH / "traffic" / f"{name}.json").read_text())
-        assert t["sources"] == "point" and t["kappa"] > 0
+        assert t["clients"] == 1
+        assert t.get("sources") == "point" and t["kappa"] > 0 or (
+            t["systems"] >= 2)
+    systems = set()
     for path in (BENCH / "configs").glob("*.json"):
         cfg = json.loads(path.read_text())
-        assert cfg["name"] == path.stem and cfg["reduced"] == []
-        assert cfg["lattice"][3] % cfg["t_shards"] == 0
+        assert cfg["name"] == path.stem
+        systems.add(cfg["system"])
+        if cfg["system"] == "lqcd_wilson":
+            assert cfg["reduced"] == []
+            assert cfg["lattice"][3] % cfg["t_shards"] == 0
+    assert systems == {p.stem for p in (BENCH / "systems").glob("*.py")}
+    for name in systems:
+        system = harness.load_system(REPO, name)
+        for attr in ("make_inputs", "Solver", "check", "control"):
+            assert callable(getattr(system, attr)), (name, attr)
+
+
+def test_harness_and_control_name_no_system():
+    """What belongs to one system lives in its own files: the harness and
+    the control name none of the Wilson solve's pieces."""
+    for name in ("harness.py", "control.py"):
+        text = (BENCH / name).read_text()
+        for word in ("kappa", "solve_dirac", "relative_residual", "fields",
+                     "lqcd", "hpl"):
+            assert word not in text, (name, word)
 
 
 def test_peaks_are_keyed_by_device_kind():

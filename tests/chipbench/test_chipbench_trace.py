@@ -106,7 +106,7 @@ def test_metric_readers_on_the_hand_made_trace():
     tr = T.reduce_profile(ProfileData.from_text_proto(HAND_MADE))
     cell = harness.load_cell(REPO, "thermal_32x8.light")
     peaks = harness.load_peaks(REPO, "TPU v5 lite")
-    solves = [harness.Solve(0, 1.0, 20, 3, None)]
+    solves = [harness.Solve(0, 1.0, {"iters": 20, "outer_iters": 3}, None)]
     ctx = harness.Context(cell, solves, solves, tr, peaks, 2)
     read = {m: harness.load_reader(REPO, m) for m in (
         "device.idle_share", "collective.exposed_share", "inner_cg_roofline",
@@ -156,7 +156,8 @@ def test_metric_readers_on_the_recorded_trace():
     tr = T.reduce_profile(ProfileData.from_file(str(RECORDED)), devices=[0])
     cell = harness.load_cell(REPO, "thermal_32x8.light")
     peaks = harness.load_peaks(REPO, "TPU v5 lite")
-    solves = [harness.Solve(i, 0.19, 3, 3, None) for i in range(2)]
+    solves = [harness.Solve(i, 0.19, {"iters": 3, "outer_iters": 3},
+                            None) for i in range(2)]
     ctx = harness.Context(cell, solves, solves, tr, peaks, 1)
     idle = harness.load_reader(REPO, "device.idle_share")(ctx)
     assert idle == pytest.approx(100 * (1 - 0.365426675 / 0.389148898))
