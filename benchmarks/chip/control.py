@@ -3,13 +3,15 @@ size, on the chip.
 
     python3 benchmarks/chip/control.py --workload <cell> --seeds 1,2,3
 
-For each seed it makes the cell's inputs, answers the first request with
-the control of the cell's system (its plain reference in the solver's
-place, one precision below what the configuration states: ``control`` in
-``benchmarks/chip/systems/<system>.py``) and judges the answer by the
-system's own ``check``, printing each number it reads beside its limit
-and the verdict.  The control runs on one chip.  The comparison is sound
-only where the check finds every control answer not correct.
+It holds the cell to its system's rules (``validate``) before it looks
+for a chip.  For each seed it makes the cell's inputs, answers the first
+request with the control of the cell's system (its plain reference in the
+solver's place, one precision below what the configuration states:
+``control`` in ``benchmarks/chip/systems/<system>.py``) and judges the
+answer by the system's own ``check``, printing each number it reads
+beside its limit and the verdict.  The control runs on one chip.  The
+comparison is sound only where the check finds every control answer not
+correct.
 """
 from __future__ import annotations
 
@@ -31,9 +33,10 @@ def main(argv=None) -> int:
         sys.path.insert(0, str(p))
     from benchmarks.chip import harness
 
-    devices = harness.require_chips(1)
     cell = harness.load_cell(ROOT, args.workload)
     system = harness.load_system(ROOT, cell.config["system"])
+    system.validate(cell.config, cell.traffic, cell.chips)
+    devices = harness.require_chips(1)
     config, traffic = cell.config, cell.traffic
     readings = []
     for seed in (int(s) for s in args.seeds.split(",")):

@@ -13,6 +13,13 @@ number, or None where it finds nothing to read), and the chip's peaks in
 
 A system module provides:
 
+- ``validate(config, traffic, chips)``: raises ``BenchError`` naming each
+  of the system's rules that the cell breaks (its configuration, its
+  traffic mix, the chips it asks for), as ``require`` does; the harness
+  calls it before any set-up, so that a cell the system refuses spends no
+  chip time.  The spec checks hand it every traffic mix, those of other
+  systems too, so it reads a traffic key with ``.get`` and refuses a mix
+  that lacks one with ``BenchError``, never ``KeyError``;
 - ``make_inputs(seed, config, traffic, devices)`` -> ``(shared,
   requests)``: the state every solve shares and the requests the window
   cycles through, made on the device from the seed;
@@ -47,6 +54,14 @@ TRACE_SOLVES = 2
 
 class BenchError(RuntimeError):
     """The run cannot give a result (no chip, unknown chip, bad cell)."""
+
+
+def require(subject: str, rules: Dict[str, bool]) -> None:
+    """Raises ``BenchError`` naming every rule of ``rules`` (rule -> kept)
+    that ``subject`` breaks."""
+    broken = [rule for rule, kept in rules.items() if not kept]
+    if broken:
+        raise BenchError(f"{subject} breaks: {'; '.join(broken)}")
 
 
 @dataclass
@@ -198,6 +213,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
 
     cell = load_cell(root, workload)
     system = load_system(root, cell.config["system"])
+    system.validate(cell.config, cell.traffic, cell.chips)
     devices = require_chips(cell.chips)
     kind = devices[0].device_kind
     peaks = load_peaks(root, kind)

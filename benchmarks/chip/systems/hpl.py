@@ -14,13 +14,32 @@ default precision multiplies bfloat16-rounded operands.
 The check is HPL 2.3's scaled residual of every answer
 (``benchmarks.chip.hpl_reference``), held to the configuration's
 ``scaled_residual_limit``.  The control is the reference's LU with its
-trailing updates one precision lower.
+trailing updates one precision lower.  ``validate`` holds a cell to
+HPL as the configuration states it.
 """
 from __future__ import annotations
 
 import math
 import sys
 from typing import List
+
+PRECISION = {"storage": "float32", "products": "float32"}
+
+
+def validate(config, traffic, chips: int) -> None:
+    """Raises ``BenchError`` naming each rule the cell breaks: only N cut,
+    float32 storage and products, N a whole number of NB blocks, and at
+    least two systems, so that no two consecutive solves share a matrix,
+    for one caller."""
+    from benchmarks.chip.harness import require
+    require(f"{config['name']} on {chips} chip(s), hpl's rules", {
+        "reduced == ['n']": config["reduced"] == ["n"],
+        "precision float32 storage and products":
+            config["precision"] == PRECISION,
+        "n % nb == 0": int(config["n"]) % int(config["nb"]) == 0,
+        "systems >= 2": int(traffic.get("systems", 0)) >= 2,
+        "one client": traffic.get("clients") == 1,
+    })
 
 
 def make_inputs(seed: int, config, traffic, devices):
@@ -49,15 +68,19 @@ class Solver:
     """One call factors one matrix anew and solves its system."""
 
     def __init__(self, config, traffic, devices):
+        # imported here and not while ``_solve`` is traced: the package's
+        # first import builds module-level arrays (``repro.lqcd.dirac``'s
+        # among them), which inside a trace would be left as tracers
+        from repro.hpl import lu
+        self.lu = lu
         self.nb = int(config["nb"])
         self.lookahead = int(config["lookahead"])
         self.precision = config["precision"]["products"]
         self.compiled = None
 
     def _solve(self, a, b):
-        from repro.hpl import lu
-        res = lu.blocked_lu(a, self.nb, lookahead=self.lookahead)
-        return lu.lu_solve(res, b, self.nb)
+        res = self.lu.blocked_lu(a, self.nb, lookahead=self.lookahead)
+        return self.lu.lu_solve(res, b, self.nb)
 
     def lower(self, request):
         """The solve as one program, lowered with its products at the

@@ -7,6 +7,7 @@ cycles through.  The check is the plain reference's true relative
 residual ``||b - M x|| / ||b||`` (``benchmarks.chip.reference``) of every
 solve, held to the configuration's ``solver.tol``.  The control is the
 reference CG one precision lower (``reference.control_solve``).
+``validate`` holds a cell to the configuration as the paper runs it.
 """
 from __future__ import annotations
 
@@ -15,6 +16,26 @@ from typing import Any, Dict, List
 
 # normal ops of the control's CG (no early exit)
 CONTROL_ITERS = 300
+PRECISION = {"outer": "float32", "inner": "bfloat16"}
+
+
+def validate(config, traffic, chips: int) -> None:
+    """Raises ``BenchError`` naming each rule the cell breaks: nothing of
+    the lattice cut, float32 outer over a bfloat16 inner CG, the T axis
+    divided evenly over ``t_shards`` chips, one chip to a shard, and the
+    point sources of a propagator at a positive kappa for one caller."""
+    from benchmarks.chip.harness import require
+    shards = int(config["t_shards"])
+    require(f"{config['name']} on {chips} chip(s), lqcd_wilson's rules", {
+        "reduced == []": config["reduced"] == [],
+        "precision float32 outer, bfloat16 inner":
+            config["precision"] == PRECISION,
+        "lattice[3] % t_shards == 0": config["lattice"][3] % shards == 0,
+        "chips == t_shards": chips == shards,
+        "kappa > 0": float(traffic.get("kappa", 0)) > 0,
+        "point sources": traffic.get("sources") == "point",
+        "one client": traffic.get("clients") == 1,
+    })
 
 
 def _mesh(config: Dict[str, Any]):

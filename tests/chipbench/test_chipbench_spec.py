@@ -1,19 +1,19 @@
 """BENCHMARK.json and the files it names: every configuration, traffic mix
 and metric reader loads under its name, the file keeps to the limits on
-names, units and bounds, and a cell or metric is added by files and
-entries alone."""
+names, units and bounds, each cell keeps the rules of its system, and a
+cell or metric is added by files and entries alone.  The checks are
+``chipbench_helpers.check_spec``'s, which name no system: each system's
+own rules are its ``validate``."""
 import json
-import re
 
 import pytest
 
-from chipbench_helpers import BENCH, REPO, SPEC, small_root
+from chipbench_helpers import (BENCH, REPO, SPEC,
+                               check_cells_and_configurations, check_files,
+                               check_names_units_and_bounds, check_spec,
+                               small_root)
 
 from benchmarks.chip import harness
-
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
 def test_top_level_keys_and_command():
@@ -30,96 +30,21 @@ def test_top_level_keys_and_command():
 
 
 def test_names_units_and_bounds():
-    names = [e["name"] for key in ("configs", "workloads", "end_to_end",
-                                   "per_layer") for e in SPEC[key]]
-    assert len(names) == len(set(names))
-    for n in names:
-        assert NAME.match(n), n
-    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        assert m["source"] in SOURCES
-    e2e = {m["name"]: m for m in SPEC["end_to_end"]}
-    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
-    for m in SPEC["end_to_end"]:
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.25
-    cells = {w["name"] for w in SPEC["workloads"]}
-    for m in SPEC["per_layer"]:
-        assert m["moves"] in e2e and set(m["workloads"]) <= cells
-        assert "\n" not in m["layer"] and m["layer"]
+    check_names_units_and_bounds(SPEC)
 
 
 def test_cells_and_configurations_load_by_name():
-    used = {w["config"] for w in SPEC["workloads"]}
-    assert used == {c["name"] for c in SPEC["configs"]}
-    files = [c["file"] for c in SPEC["configs"]]
-    assert len(files) == len(set(files))
-    assert len({(c["source"], tuple(c["reduced"]))
-                for c in SPEC["configs"]}) == len(SPEC["configs"])
-    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
-    assert four <= max(1, len(SPEC["workloads"]) // 2)
-    for w in SPEC["workloads"]:
-        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
-        cell = harness.load_cell(REPO, w["name"])
-        assert cell.config["name"] == w["config"]
-        assert cell.end_to_end and cell.per_layer
-        assert {m["name"] for m in cell.end_to_end} >= {"setup_s", "solve_s"}
-        if cell.config["system"] == "lqcd_wilson":
-            assert cell.chips == cell.config["t_shards"] == w["chips"]
-            assert cell.traffic["kappa"] > 0
-    for c in SPEC["configs"]:
-        cfg = json.loads((REPO / c["file"]).read_text())
-        assert c["file"].startswith("benchmarks/chip/")
-        assert cfg["reduced"] == c["reduced"]
-        if cfg["system"] == "lqcd_wilson":
-            assert cfg["reduced"] == []
-            assert cfg["precision"] == {"outer": "float32",
-                                        "inner": "bfloat16"}
-        else:
-            assert cfg["system"] == "hpl" and cfg["reduced"] == ["n"]
-            assert cfg["precision"] == {"storage": "float32",
-                                        "products": "float32"}
-            assert cfg["n"] % cfg["nb"] == 0
+    """Every cell loads, reports ``setup_s`` and ``solve_s``, and is
+    accepted by its system's ``validate``."""
+    check_cells_and_configurations(REPO, SPEC)
 
 
 def test_every_metric_has_a_reader():
     """Each per-layer metric, traffic mix, configuration and system in
-    BENCHMARK.json has its file; the files of the four-chip cell, which
-    waits for a later benchmark PR, load as well."""
-    readers = {p.stem for p in (BENCH / "metrics").glob("*.py")}
-    assert {m["name"] for m in SPEC["per_layer"]} <= readers
-    for name in readers:
-        assert callable(harness.load_reader(REPO, name))
-    traffic = {p.stem for p in (BENCH / "traffic").glob("*.json")}
-    assert {w["traffic"] for w in SPEC["workloads"]} <= traffic
-    for name in traffic:
-        t = json.loads((BENCH / "traffic" / f"{name}.json").read_text())
-        assert t["clients"] == 1
-        assert t.get("sources") == "point" and t["kappa"] > 0 or (
-            t["systems"] >= 2)
-    systems = set()
-    for path in (BENCH / "configs").glob("*.json"):
-        cfg = json.loads(path.read_text())
-        assert cfg["name"] == path.stem
-        systems.add(cfg["system"])
-        if cfg["system"] == "lqcd_wilson":
-            assert cfg["reduced"] == []
-            assert cfg["lattice"][3] % cfg["t_shards"] == 0
-    assert systems == {p.stem for p in (BENCH / "systems").glob("*.py")}
-    for name in systems:
-        system = harness.load_system(REPO, name)
-        for attr in ("make_inputs", "Solver", "check", "control"):
-            assert callable(getattr(system, attr)), (name, attr)
-
-
-def test_harness_and_control_name_no_system():
-    """What belongs to one system lives in its own files: the harness and
-    the control name none of the Wilson solve's pieces."""
-    for name in ("harness.py", "control.py"):
-        text = (BENCH / name).read_text()
-        for word in ("kappa", "solve_dirac", "relative_residual", "fields",
-                     "lqcd", "hpl"):
-            assert word not in text, (name, word)
+    BENCHMARK.json has its file, and the configuration of the four-chip
+    cell, which waits for a later benchmark PR, forms a cell that its
+    system accepts."""
+    check_files(REPO, SPEC)
 
 
 def test_peaks_are_keyed_by_device_kind():
@@ -155,6 +80,7 @@ def test_new_cell_and_metric_are_found_by_name(tmp_path):
         if p.is_file() and "__pycache__" not in p.parts:
             assert (root / "benchmarks/chip" / p.relative_to(BENCH)
                     ).read_bytes() == p.read_bytes()
+    check_spec(root)
     cell = harness.load_cell(root, "small.cell")
     assert cell.traffic["kappa"] == 0.15
     assert "solve.count" in [m["name"] for m in cell.per_layer]

@@ -1,23 +1,33 @@
 """The system under test is named by the configuration: a system added as
-new files is found by name and driven by the harness, and the HPL system
-runs on the CPU, is correct, and fails the comparison that decides
-``correct`` when its timed path is broken underneath it."""
+new files is found by name, passes the benchmark's spec checks and is
+driven by the harness; each system refuses a cell that breaks one of its
+rules before any input is made; and the HPL system runs on the CPU, is
+correct, and fails the comparison that decides ``correct`` when its timed
+path is broken underneath it."""
 import json
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench_helpers import (BENCH, close_window_after, on_cpu,
-                               patch_system, run_small, small_hpl_root,
-                               small_root)
+from chipbench_helpers import (BENCH, check_spec, close_window_after, on_cpu,
+                               patch_system, root_with_cell, run_small,
+                               small_hpl_root, small_root)
 
-from benchmarks.chip import harness, hpl_reference, work
+from benchmarks.chip import control, harness, hpl_reference, work
 
 TOY_SYSTEM = '''
 import jax
 import numpy as np
+
+
+def validate(config, traffic, chips):
+    from benchmarks.chip.harness import require
+    require("toy_scale", {"n >= 1": config["n"] >= 1,
+                          "requests >= 1": traffic.get("requests", 0) >= 1,
+                          "one chip": chips == 1})
 
 
 def make_inputs(seed, config, traffic, devices):
@@ -61,9 +71,10 @@ def harness_env(monkeypatch):
 
 def test_toy_system_added_as_files_is_found_and_run(tmp_path, harness_env):
     """A system, its configuration, traffic, a reader and a cell added to
-    a copy of the benchmark as new files and entries only: the harness
-    loads the system by the configuration's name and runs it end to end;
-    every committed file of the copy is byte-for-byte the original."""
+    a copy of the benchmark as new files and entries only: the copy passes
+    the same spec checks as the repository, and the harness loads the
+    system by the configuration's name and runs it end to end; every
+    committed file of the copy is byte-for-byte the original."""
     root = small_root(tmp_path)
     chip = root / "benchmarks" / "chip"
     (chip / "systems" / "toy_scale.py").write_text(TOY_SYSTEM)
@@ -76,7 +87,7 @@ def test_toy_system_added_as_files_is_found_and_run(tmp_path, harness_env):
         "    return sum(s.counters['scale'] for s in ctx.solves) / "
         "len(ctx.solves)\n")
     spec = json.loads((root / "BENCHMARK.json").read_text())
-    spec["configs"].append({"name": "toy", "source": "test",
+    spec["configs"].append({"name": "toy", "source": "toy-test",
                             "file": "benchmarks/chip/configs/toy.json",
                             "reduced": [], "why": "test"})
     spec["workloads"].append({"name": "toy.three", "config": "toy",
@@ -89,6 +100,7 @@ def test_toy_system_added_as_files_is_found_and_run(tmp_path, harness_env):
     for p in BENCH.rglob("*"):
         if p.is_file() and "__pycache__" not in p.parts:
             assert (chip / p.relative_to(BENCH)).read_bytes() == p.read_bytes()
+    check_spec(root)
     out = run_small(root, "toy.three", seconds=0.2)
     assert out["correct"] is True and out["attempted"] >= 1
     assert out["checks"] == {"error_max": {"value": 0.0, "limit": 0.0}}
@@ -96,6 +108,110 @@ def test_toy_system_added_as_files_is_found_and_run(tmp_path, harness_env):
     traced = run_small(root, "toy.three", seconds=0.0, trace=True)
     assert traced["metrics"]["toy.mean_scale"]["value"] == pytest.approx(
         1.5)                            # the two traced solves: 1, then 2
+
+
+@pytest.mark.parametrize("name", ["harness.py", "control.py"])
+def test_harness_and_control_name_no_system(name):
+    """What belongs to one system lives in its own files: the harness and
+    the control name none of the systems found under ``systems/``, nor
+    any piece of the Wilson solve or of HPL."""
+    systems = [p.stem for p in (BENCH / "systems").glob("*.py")]
+    assert len(systems) >= 2
+    text = (BENCH / name).read_text()
+    for word in systems + ["kappa", "solve_dirac", "relative_residual",
+                           "fields", "lqcd", "hpl", "blocked_lu",
+                           "scaled_residual"]:
+        assert word not in text, (name, word)
+
+
+# one case for each rule a system holds its cells to: (the configuration
+# and traffic of a repository cell, what breaks the rule, the rule)
+BROKEN_RULES = {
+    "wilson_reduced": ("thermal_32x8", "light", {"reduced": ["lattice"]},
+                       {}, 1, "reduced == []"),
+    "wilson_precision": ("thermal_32x8", "light",
+                         {"precision": {"outer": "float32",
+                                        "inner": "float32"}},
+                         {}, 1, "precision float32 outer, bfloat16 inner"),
+    "wilson_t_split": ("thermal_32x8", "light",
+                       {"lattice": [32, 32, 32, 6], "t_shards": 4}, {}, 4,
+                       "lattice[3] % t_shards == 0"),
+    "wilson_chips": ("thermal_32x8", "light", {}, {}, 4,
+                     "chips == t_shards"),
+    "wilson_kappa": ("thermal_32x8", "light", {}, {"kappa": 0.0}, 1,
+                     "kappa > 0"),
+    "wilson_point_sources": ("thermal_32x8", "light", {},
+                             {"sources": "volume"}, 1, "point sources"),
+    "wilson_one_client": ("thermal_32x8", "light", {}, {"clients": 2}, 1,
+                          "one client"),
+    "hpl_reduced": ("hpl_n8192", "factor", {"reduced": []}, {}, 1,
+                    "reduced == ['n']"),
+    "hpl_precision": ("hpl_n8192", "factor",
+                      {"precision": {"storage": "float32",
+                                     "products": "bfloat16"}},
+                      {}, 1, "precision float32 storage and products"),
+    "hpl_whole_blocks": ("hpl_n8192", "factor", {"n": 8000}, {}, 1,
+                         "n % nb == 0"),
+    "hpl_systems": ("hpl_n8192", "factor", {}, {"systems": 1}, 1,
+                    "systems >= 2"),
+    "hpl_one_client": ("hpl_n8192", "factor", {}, {"clients": 2}, 1,
+                       "one client"),
+}
+
+
+def _broken_root(tmp_path, base, mix, cfg_changes, traffic_changes, chips):
+    """A copy of the benchmark with a cell ``broken.cell`` on ``base``
+    changed by ``cfg_changes`` and a traffic mix ``broken``, ``mix``
+    changed by ``traffic_changes``; and the two as the system gets them."""
+    traffic = dict(json.loads((BENCH / "traffic" / f"{mix}.json")
+                              .read_text()), **traffic_changes)
+    root = root_with_cell(tmp_path, base, "broken", chips, name="broken",
+                          **cfg_changes)
+    (root / "benchmarks/chip/traffic/broken.json").write_text(
+        json.dumps(traffic))
+    config = json.loads((root / "benchmarks/chip/configs/broken.json")
+                        .read_text())
+    return root, config, traffic
+
+
+@pytest.mark.parametrize("case", list(BROKEN_RULES))
+def test_cell_that_breaks_a_rule_of_its_system_is_refused(
+        tmp_path, monkeypatch, harness_env, case):
+    """The system's ``validate`` names the broken rule, the spec checks
+    refuse the copy that holds the cell, and the harness refuses the cell
+    with ``BenchError`` before its system makes any input; the cell as
+    the repository has it is accepted."""
+    base, mix, cfg_changes, traffic_changes, chips, rule = BROKEN_RULES[case]
+    config = json.loads((BENCH / "configs" / f"{base}.json").read_text())
+    system = harness.load_system(BENCH.parents[1], config["system"])
+    system.validate(config, json.loads(
+        (BENCH / "traffic" / f"{mix}.json").read_text()), 1)
+    root, config, traffic = _broken_root(tmp_path, base, mix, cfg_changes,
+                                         traffic_changes, chips)
+    match = re.escape(rule)
+    with pytest.raises(harness.BenchError, match=match):
+        system.validate(config, traffic, chips)
+    with pytest.raises(harness.BenchError, match=match):
+        check_spec(root)
+
+    def no_inputs(monkeypatch, module):
+        def make_inputs(*args, **kw):
+            raise AssertionError("inputs made for a refused cell")
+        monkeypatch.setattr(module, "make_inputs", make_inputs)
+    patch_system(monkeypatch, no_inputs)
+    with pytest.raises(harness.BenchError, match=match):
+        run_small(root, "broken.cell")
+
+
+def test_control_refuses_a_cell_that_breaks_its_systems_rules(
+        tmp_path, monkeypatch):
+    """``control.py`` holds a cell to its system's rules before it looks
+    for a chip."""
+    root, _, _ = _broken_root(tmp_path, "thermal_32x8", "light", {},
+                              {"clients": 2}, 1)
+    monkeypatch.setattr(control, "ROOT", root)
+    with pytest.raises(harness.BenchError, match="one client"):
+        control.main(["--workload", "broken.cell", "--seeds", "1"])
 
 
 def test_unknown_system_is_an_error(tmp_path, harness_env):
